@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-135M]."""
+"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-360M]."""
 
 from repro.configs.base import ModelConfig
 

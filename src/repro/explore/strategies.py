@@ -217,10 +217,11 @@ def _gene_seeds(cands: List[int], table: np.ndarray,
 
 
 def _rank_mesh(rank_devices: Optional[int]):
-    """1-D device mesh for sharded Pareto ranking, or None.
+    """1-D mesh of the first ``rank_devices`` devices for sharded Pareto
+    ranking, or None for one device.
 
-    Clamps to the locally visible device count with a warning — a spec
-    written for an 8-device host should still run (slower) on a laptop.
+    Raises when fewer devices are visible than asked for: a search that was
+    meant to span several chips must not quietly run on fewer.
     """
     if not rank_devices or rank_devices <= 1:
         return None
@@ -228,12 +229,9 @@ def _rank_mesh(rank_devices: Optional[int]):
     from jax.sharding import Mesh
     devs = jax.devices()
     if len(devs) < rank_devices:
-        warnings.warn(
+        raise ValueError(
             f"jit_nsga2: rank_devices={rank_devices} but only {len(devs)} "
-            f"device(s) visible; using {len(devs)}", stacklevel=2)
-        rank_devices = len(devs)
-    if rank_devices <= 1:
-        return None
+            f"{devs[0].platform} device(s) are visible")
     return Mesh(np.asarray(devs[:rank_devices]), ("rank",))
 
 

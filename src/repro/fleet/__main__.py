@@ -51,6 +51,8 @@ def _cmd_run(args) -> int:
 
 def _cmd_worker(args) -> int:
     from repro.fleet.worker import run_worker
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # failed attempts are recorded in the manifest and retried/merged there;
     # the process itself succeeded if the loop ran to completion
     run_worker(args.manifest, worker_id=args.worker_id,

@@ -8,6 +8,12 @@ on a shared filesystem).  :func:`run_fleet` is the one-call path: reclaim
 stale claims, start workers, wait, merge — and because every step is
 manifest-driven, running it again after a crash (or Ctrl-C) resumes instead
 of recomputing.
+
+A JAX process takes every accelerator chip of its host, and a second
+process that asks for them fails or hangs.  So where the workers' JAX
+backend is an accelerator, one local worker runs per host; any number run
+when it is the CPU.  The parent never imports JAX itself: it asks a
+short-lived child which backend the workers will get.
 """
 
 from __future__ import annotations
@@ -16,6 +22,7 @@ import os
 import subprocess
 import sys
 import time
+import warnings
 from typing import Dict, List, Optional, Sequence
 
 from repro.explore.campaign import CampaignReport
@@ -46,10 +53,32 @@ def worker_command(manifest_dir: str, worker_id: Optional[str] = None,
     return cmd
 
 
+def worker_backend(env: Dict[str, str]) -> str:
+    """JAX backend a worker started with ``env`` gets ('cpu', 'tpu', ...),
+    asked of a child process that exits — and so frees any chip — before
+    the workers start."""
+    if env.get("JAX_PLATFORMS", "").strip() == "cpu":
+        return "cpu"
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        env=env, capture_output=True, text=True, timeout=300, check=True)
+    return probe.stdout.split()[-1]
+
+
 def start_workers(manifest_dir: str, n: int, verbose: bool = False
                   ) -> List[subprocess.Popen]:
-    """Spawn ``n`` local worker processes against ``manifest_dir``."""
+    """Spawn ``n`` local worker processes against ``manifest_dir`` — one
+    only when the workers' backend is an accelerator (see module
+    docstring)."""
     env = _worker_env()
+    if n > 1:
+        backend = worker_backend(env)
+        if backend != "cpu":
+            warnings.warn(
+                f"fleet: starting 1 local worker instead of {n}: a JAX "
+                f"process holds every {backend} chip of this host",
+                stacklevel=2)
+            n = 1
     return [subprocess.Popen(worker_command(manifest_dir, verbose=verbose),
                              env=env) for _ in range(n)]
 

@@ -4,6 +4,10 @@
 — a correctness harness; compiled Mosaic on real TPU), 'ref' uses the
 pure-jnp oracle, 'auto' picks ref on CPU backends and pallas on TPU.
 Dry-run lowering always uses 'ref' (DESIGN.md §6).
+
+A 'pallas' request for a shape the kernel cannot tile raises: a wrapper
+never swaps in the reference behind the caller's back, so a run that asked
+for the kernel either ran it or failed.
 """
 
 from __future__ import annotations
@@ -60,7 +64,10 @@ _PALLAS_COL_TILE = 256
 
 
 def _row_tile(block: int) -> int:
-    return max(32, block // 32 * 32)
+    # the packed output block is (rows // 32, cols): rows must give it a
+    # whole number of 8-sublane tiles on TPU, so the row tile is a multiple
+    # of 256 whatever smaller block the caller asks for
+    return max(256, block // 256 * 256)
 
 
 def _packed_rows(Fr, cvr, Fq, cvq, block: int, impl: str) -> jnp.ndarray:
@@ -84,8 +91,8 @@ def packed_domination(F, CV, *, block: int = 1024, impl: str = "auto",
     bit-identical to packing the dense ``domination_matrix``, but the dense
     (n, n[, m]) boolean temporaries never exist: peak working memory is the
     packed words plus one (block, n) tile.  With a 1-D ``mesh`` the
-    dominator row-tiles are sharded across its devices through the
-    ``repro.nn.sharding`` shard_map shim.
+    dominator row-tiles are sharded across its devices with
+    ``jax.shard_map``.
     """
     impl = resolve_rank_impl(impl)
     F = jnp.asarray(F, jnp.float32)
@@ -94,14 +101,12 @@ def packed_domination(F, CV, *, block: int = 1024, impl: str = "auto",
     W = (n + 31) // 32
     if mesh is not None and mesh.size > 1:
         from jax.sharding import PartitionSpec as P
-
-        from repro.nn.sharding import shard_map
         ax = mesh.axis_names[0]
         Fr, cvr = _ref._pad_rows(F, CV, 32 * mesh.size)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda fr, cr, fq, cq: _packed_rows(fr, cr, fq, cq, block, impl),
             mesh=mesh, in_specs=(P(ax, None), P(ax), P(None, None), P(None)),
-            out_specs=P(ax, None), check_rep=False)
+            out_specs=P(ax, None), check_vma=False)
         return fn(Fr, cvr, F, CV)[:W]
     return _packed_rows(F, CV, F, CV, block, impl)[:W]
 
@@ -134,8 +139,10 @@ def quant_matmul(x, w_q, w_scale, x_scale, impl: str = "pallas"):
     from repro.kernels.quant_matmul import quant_matmul as k
     m, kk = x.shape
     n = w_q.shape[1]
-    if m % 128 or n % 128 or kk % 128:   # fall back off-grid shapes
-        return _ref.quant_matmul(x, w_q, w_scale, x_scale)
+    if m % 128 or n % 128 or kk % 128:
+        raise ValueError(
+            f"quant_matmul: pallas needs M, K, N multiples of 128, got "
+            f"{(m, kk, n)}; pad the operands or pass impl='ref'")
     return k(x, w_q, w_scale, x_scale, interpret=_interpret())
 
 
@@ -155,10 +162,9 @@ def window_attn(q, k, v, window: int, impl: str = "pallas"):
         return _ref.window_attn(q, k_e, v_e, window)
     from repro.kernels.window_attn import window_attn as kern
     t = q.shape[1]
-    bq = bk = 128 if t % 128 == 0 and window % 128 == 0 else None
-    if bq is None:
-        group = q.shape[2] // k.shape[2]
-        return _ref.window_attn(q, jnp.repeat(k, group, axis=2),
-                                jnp.repeat(v, group, axis=2), window)
-    return kern(q, k, v, window=window, bq=bq, bk=bk,
+    if t % 128 or window % 128:
+        raise ValueError(
+            f"window_attn: pallas needs T and window multiples of 128, got "
+            f"T={t}, window={window}; pass impl='ref' for other shapes")
+    return kern(q, k, v, window=window, bq=128, bk=128,
                 interpret=_interpret())

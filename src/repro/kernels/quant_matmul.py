@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, wq_ref, wscale_ref, xscale_ref, o_ref):
@@ -37,7 +38,7 @@ def _kernel(x_ref, wq_ref, wscale_ref, xscale_ref, o_ref):
 
     @pl.when(k_idx == nk - 1)
     def _epilogue():
-        o_ref[...] = o_ref[...] * x_scale * wscale_ref[...][None, :]
+        o_ref[...] = o_ref[...] * x_scale * wscale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -56,10 +57,12 @@ def quant_matmul(x: jnp.ndarray, w_q: jnp.ndarray, w_scale: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((1,), lambda i, j, kk: (0,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            # the scalar activation scale lives in scalar memory
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, w_q, w_scale, jnp.reshape(x_scale, (1,)).astype(jnp.float32))
+    )(x, w_q, w_scale.reshape(1, n),
+      jnp.reshape(x_scale, (1,)).astype(jnp.float32))

@@ -38,24 +38,28 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 # -- pareto_rank ---------------------------------------------------------------
 
 def dominates_tile(Fp: jnp.ndarray, cvp: jnp.ndarray,
-                   Fq: jnp.ndarray, cvq: jnp.ndarray) -> jnp.ndarray:
-    """Deb constrained-domination tile: out[i, j] = (Fp[i], cvp[i]) dominates
-    (Fq[j], cvq[j]).  The objective loop is unrolled over the (static, small)
-    objective count so no (rows, cols, m) temporary is ever materialized —
-    the building block every blocked/tiled Pareto primitive shares."""
-    rows, cols = Fp.shape[0], Fq.shape[0]
-    all_le = jnp.ones((rows, cols), dtype=bool)
-    any_lt = jnp.zeros((rows, cols), dtype=bool)
+                   FqT: jnp.ndarray, cvq: jnp.ndarray) -> jnp.ndarray:
+    """Deb constrained-domination tile: out[i, j] = (Fp[i], cvp[i, 0])
+    dominates (FqT[:, j], cvq[0, j]).
+
+    Rows arrive as columns — Fp (rows, m), cvp (rows, 1) — and columns as
+    rows — FqT (m, cols), cvq (1, cols) — so every operand is a plain static
+    slice that broadcasts to (rows, cols): no gather and no relayout, which
+    is what Mosaic needs to compile it.  The objective loop is unrolled over
+    the (static, small) objective count so no (rows, cols, m) temporary is
+    ever materialized — the building block every blocked/tiled Pareto
+    primitive shares."""
+    all_le = any_lt = None
     for j in range(Fp.shape[1]):
-        a, b = Fp[:, j, None], Fq[None, :, j]
-        all_le &= a <= b
-        any_lt |= a < b
-    feas_p, feas_q = (cvp <= 0)[:, None], (cvq <= 0)[None, :]
-    cv_lt = cvp[:, None] < cvq[None, :]
-    return jnp.where(feas_p & ~feas_q, True,
-                     jnp.where(feas_q & ~feas_p, False,
-                               jnp.where(~feas_p & ~feas_q, cv_lt,
-                                         all_le & any_lt)))
+        a, b = Fp[:, j:j + 1], FqT[j:j + 1, :]
+        le, lt = a <= b, a < b
+        all_le = le if all_le is None else all_le & le
+        any_lt = lt if any_lt is None else any_lt | lt
+    feas_p, feas_q = cvp <= 0, cvq <= 0
+    # feasible beats infeasible; two infeasible compare by violation; two
+    # feasible by Pareto domination
+    return ((feas_p & ~feas_q) | (~feas_p & ~feas_q & (cvp < cvq))
+            | (feas_p & feas_q & all_le & any_lt))
 
 
 def _pack_rows(B: jnp.ndarray) -> jnp.ndarray:
@@ -89,11 +93,12 @@ def packed_domination(Fr: jnp.ndarray, cvr: jnp.ndarray,
     r = Fr.shape[0]
     rows = max(32, min(block, r + (-r) % 32) // 32 * 32)
     Fr, cvr = _pad_rows(Fr, cvr, rows)
+    FqT, cvq = Fq.T, cvq[None, :]
     def tile(args):
         fp, cp = args
-        return _pack_rows(dominates_tile(fp, cp, Fq, cvq))
+        return _pack_rows(dominates_tile(fp, cp, FqT, cvq))
     words = jax.lax.map(tile, (Fr.reshape(-1, rows, Fr.shape[1]),
-                               cvr.reshape(-1, rows)))
+                               cvr.reshape(-1, rows, 1)))
     return words.reshape(-1, Fq.shape[0])[: (r + 31) // 32]
 
 
@@ -109,13 +114,14 @@ def domination_counts(F: jnp.ndarray, CV: jnp.ndarray,
     rows = max(32, min(block, n + (-n) % 32) // 32 * 32)
     Fp, cvp = _pad_rows(F, CV, rows)
     ap = jnp.pad(alive, (0, Fp.shape[0] - n))
+    FT, cvq = F.T, CV[None, :]
     def step(acc, args):
         fp, cp, al = args
-        d = dominates_tile(fp, cp, F, CV) & al[:, None]
+        d = dominates_tile(fp, cp, FT, cvq) & al[:, None]
         return acc + jnp.sum(d, axis=0, dtype=jnp.int32), None
     acc, _ = jax.lax.scan(
         step, jnp.zeros(n, dtype=jnp.int32),
-        (Fp.reshape(-1, rows, F.shape[1]), cvp.reshape(-1, rows),
+        (Fp.reshape(-1, rows, F.shape[1]), cvp.reshape(-1, rows, 1),
          ap.reshape(-1, rows)))
     return acc
 

@@ -8,7 +8,9 @@ softmax stats (m, l) and the output accumulator live in VMEM scratch across
 it.  The k/v block index is derived from (query block, kv step) in the
 BlockSpec index map (clamped at 0; out-of-range positions are masked).
 GQA is handled by mapping query head h to kv head h // group in the k/v
-index maps — no materialized head broadcast.
+index maps — no materialized head broadcast.  The wrapper moves heads ahead
+of time, (B,T,H,hd) -> (B,H,T,hd), so every block ends in a (rows, hd)
+tile that Mosaic can lay out.
 """
 
 from __future__ import annotations
@@ -19,11 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -40,28 +38,27 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                    # (bq, hd)
-    k = k_ref[0, :, 0, :]                    # (bk, hd)
-    v = v_ref[0, :, 0, :]                    # (bk, hd)
+    q = q_ref[0, 0]                          # (bq, hd)
+    k = k_ref[0, 0]                          # (bk, hd)
+    v = v_ref[0, 0]                          # (bk, hd)
     hd = q.shape[-1]
 
-    q_pos = qi * bq + jax.lax.iota(jnp.int32, bq)
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kv_blk = qi + j - (nj - 1)               # may be negative (clamped in map)
-    k_pos = jnp.maximum(kv_blk, 0) * bk + jax.lax.iota(jnp.int32, bk)
-    valid = ((kv_blk >= 0)
-             & (k_pos[None, :] <= q_pos[:, None])
-             & (k_pos[None, :] > q_pos[:, None] - window))
+    k_pos = (jnp.maximum(kv_blk, 0) * bk
+             + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    valid = (kv_blk >= 0) & (k_pos <= q_pos) & (k_pos > q_pos - window)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) / jnp.sqrt(
         jnp.float32(hd))
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
+    m_prev = m_scr[...]                      # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = (acc_scr[...] * alpha[:, None]
+    p = jnp.exp(s - m_new)
+    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_scr[...] = (acc_scr[...] * alpha
                     + jnp.dot(p, v.astype(jnp.float32),
                               preferred_element_type=jnp.float32))
     m_scr[...] = m_new
@@ -69,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(j == nj - 1)
     def _emit():
         denom = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -87,22 +84,24 @@ def window_attn(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     grid = (b, h, t // bq, nj)
 
     def kv_map(bi, hi, qi, j):
-        return bi, jnp.maximum(qi + j - (nj - 1), 0), hi // group, 0
-    scratch = [] if _VMEM is None else [
-        _VMEM((bq,), jnp.float32), _VMEM((bq,), jnp.float32),
-        _VMEM((bq, hd), jnp.float32)]
+        return bi, hi // group, jnp.maximum(qi + j - (nj - 1), 0), 0
+    scratch = [pltpu.VMEM((bq, 1), jnp.float32),
+               pltpu.VMEM((bq, 1), jnp.float32),
+               pltpu.VMEM((bq, hd), jnp.float32)]
     kern = functools.partial(_kernel, bq=bq, bk=bk, window=window)
-    return pl.pallas_call(
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda bi, hi, qi, j: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bq, hd), lambda bi, hi, qi, j: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda bi, hi, qi, j: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda bi, hi, qi, j: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, hd), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(q, k, v)
+    )(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(out)

@@ -40,6 +40,7 @@ from repro.explore import (ExplorationSpec, ModelRef, OnlineRepartitioner,
 from repro.models.registry import ARCH_IDS, build_model, get_config
 from repro.obs import NOOP_OBS, Obs, write_chrome_trace
 from repro.utils.atomicio import atomic_write_json
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def drift_schedule(base: SystemSpec):
@@ -53,6 +54,7 @@ def drift_schedule(base: SystemSpec):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m", choices=ARCH_IDS)
     ap.add_argument("--link", default="eth10",

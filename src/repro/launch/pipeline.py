@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.nn.sharding import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models.decoder import DecoderLM, _scan_blocks
 from repro.nn.layers import rms_norm
@@ -67,7 +65,7 @@ def pipelined_apply(model: DecoderLM, params: Dict[str, Any], batch: Dict,
     # microbatches is handled by the outer jit); inside shard_map we only
     # split the stage axis.
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(stage_axis), P(), P()),
         out_specs=P(),
         check_vma=False)

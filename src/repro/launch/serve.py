@@ -1,7 +1,8 @@
 """Serving driver — the paper-kind end-to-end example, now on the
 ``repro.serve`` runtime.
 
-Trains (briefly) a reduced model, lets the explorer pick the Def.-2 cut
+Trains (briefly) a reduced model — or, with ``--full-width``, takes the
+config at its published widths — lets the explorer pick the Def.-2 cut
 for an embedded two-platform system, then serves a synthetic Poisson
 traffic stream over partitioned stages with continuous batching:
 
@@ -21,6 +22,7 @@ traffic stream over partitioned stages with continuous batching:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -33,14 +35,19 @@ from repro.models.registry import ARCH_IDS, build_model, get_config
 from repro.obs import NOOP_OBS, Obs, write_chrome_trace
 from repro.optim.optimizers import get_optimizer
 from repro.serve import (PipelineServeEngine, ReplicaRouter, ServeLink,
-                         poisson_traffic)
+                         ServeReport, poisson_traffic)
 from repro.serving.pipeline import PartitionedLMRunner
 from repro.training.train_lib import make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The serve entry point's command line (``argv`` None reads ``sys.argv``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m", choices=ARCH_IDS)
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the config at its published widths instead "
+                         "of ModelConfig.reduced()")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
@@ -54,10 +61,28 @@ def main():
                          "(open in Perfetto, or `python -m repro.obs PATH`)")
     ap.add_argument("--metrics", default=None, metavar="PATH",
                     help="write a JSON metrics snapshot after the run")
-    args = ap.parse_args()
-    obs = Obs.on() if (args.trace or args.metrics) else NOOP_OBS
+    return ap.parse_args(argv)
 
-    cfg = get_config(args.arch).reduced()
+
+@dataclasses.dataclass
+class ServeRun:
+    """What one :func:`serve` call built and measured: the explorer-cut
+    runner (which holds the model and its weights), whether that cut is the
+    explorer's choice, the traffic, and the routed async and serial-handoff
+    reports over that traffic."""
+    runner: PartitionedLMRunner
+    explorer_cut: bool
+    requests: list
+    rep_async: ServeReport
+    rep_serial: ServeReport
+
+
+def serve(args: argparse.Namespace, obs: Obs = NOOP_OBS) -> ServeRun:
+    """Build the model, let the explorer pick the cut, and serve the same
+    Poisson traffic through async and serial replicas behind the router."""
+    cfg = get_config(args.arch)
+    if not args.full_width:
+        cfg = cfg.reduced()
     if cfg.family not in ("dense",):
         raise SystemExit(f"--arch {args.arch}: partitioned serving needs a "
                          "dense decoder (block-boundary stage cuts)")
@@ -65,17 +90,19 @@ def main():
     key = jax.random.PRNGKey(0)
     params, state = model.init(key)
 
-    # brief warm training so generations aren't pure noise
-    opt = get_optimizer("adamw", 1e-3)
-    opt_state = opt.init(params)
-    step_fn = jax.jit(make_train_step(model, cfg, opt))
-    for i in range(args.warm_steps):
-        b = make_batch_for(cfg, 8, 64, seed=i)
-        b = {k: jnp.asarray(v) for k, v in b.items()}
-        params, opt_state, state, metrics = step_fn(params, opt_state,
-                                                    state, b)
-    print(f"[serve] warm-trained {cfg.arch_id} reduced to "
-          f"loss={float(metrics['loss']):.3f}")
+    if args.warm_steps > 0:
+        # brief warm training so generations aren't pure noise
+        opt = get_optimizer("adamw", 1e-3)
+        opt_state = opt.init(params)
+        step_fn = jax.jit(make_train_step(model, cfg, opt))
+        for i in range(args.warm_steps):
+            b = make_batch_for(cfg, 8, 64, seed=i)
+            b = {k: jnp.asarray(v) for k, v in b.items()}
+            params, opt_state, state, metrics = step_fn(params, opt_state,
+                                                        state, b)
+        del opt_state
+        print(f"[serve] warm-trained {cfg.arch_id} to "
+              f"loss={float(metrics['loss']):.3f}")
 
     # 1. the explorer picks the cut for a two-platform embedded system
     graph = model.to_graph(args.prompt_len)
@@ -88,8 +115,15 @@ def main():
                        search=SearchSettings(seed=0))
     sel = er.selected.cuts if er.selected is not None else (1,)
     cuts = lm_block_cuts(sel, cfg.n_layers)
+    explorer_cut = (er.selected is not None
+                    and not all(c < 0 for c in sel))
+    # lm_block_cuts splits in the middle when the explorer chose no cut,
+    # e.g. when no cut of the model fits the platforms' memory
+    fallback = (f" (explorer chose no cut among {len(er.candidates)} "
+                "feasible position(s): middle split)"
+                if all(c < 0 for c in sel) else "")
     print(f"[serve] explorer selected schedule cuts {tuple(sel)} "
-          f"-> block cuts {cuts}")
+          f"-> block cuts {cuts}{fallback}")
 
     # 2. traffic + N async replicas behind the least-outstanding router
     runner = PartitionedLMRunner(model, params, cuts=cuts)
@@ -115,6 +149,15 @@ def main():
                               obs=obs).serve(list(reqs), realtime=False)
     rep_serial = ReplicaRouter(make_replicas("serial")).serve(
         list(reqs), realtime=False)
+    return ServeRun(runner, explorer_cut, reqs, rep_async, rep_serial)
+
+
+def main(argv=None) -> int:
+    enable_compile_cache()
+    args = parse_args(argv)
+    obs = Obs.on() if (args.trace or args.metrics) else NOOP_OBS
+    run = serve(args, obs)
+    rep_async, rep_serial = run.rep_async, run.rep_serial
 
     # 3. the report: throughput, Def.-4 context, per-request percentiles
     a, s = rep_async.summary(), rep_serial.summary()

@@ -80,7 +80,7 @@ def _maybe_batch_local(fn, args, n_out: int, axes_override=None):
     axes_override: explicit (axis-name-or-tuple, total-size) for the group
     axis — used by the fine-grained (batch × seq-shard) grouping."""
     from jax.sharding import PartitionSpec as P
-    from repro.nn.sharding import current_mesh, shard_map
+    from repro.nn.sharding import current_mesh
     mesh = current_mesh()
     if axes_override is not None:
         bax, n = axes_override
@@ -97,8 +97,8 @@ def _maybe_batch_local(fn, args, n_out: int, axes_override=None):
     flat, treedef = jax.tree_util.tree_flatten(out_shapes)
     out_specs = jax.tree_util.tree_unflatten(
         treedef, [spec_for(s.shape) for s in flat])
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def _dispatch(x, idx, cap: int, e: int, k: int, axes_override=None):

@@ -35,37 +35,6 @@ Axis = Union[str, Tuple[str, ...], None]
 
 _ctx = threading.local()
 
-try:                                      # JAX >= 0.6: top-level export
-    from jax import shard_map as _jax_shard_map
-except ImportError:                       # JAX 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma; pick the
-# spelling from the actual signature, not the import location (transition
-# releases exported jax.shard_map while still spelling it check_rep)
-import inspect as _inspect
-_SHARD_MAP_KWARG = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_jax_shard_map).parameters
-    else "check_rep")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              check_rep=None, **kwargs):
-    """Version-portable ``shard_map``.
-
-    Newer JAX exports ``jax.shard_map`` and spells the replication-check
-    kwarg ``check_vma``; 0.4.x only has ``jax.experimental.shard_map`` with
-    ``check_rep``.  Accepts either spelling and forwards whichever the
-    installed JAX understands.
-    """
-    check = check_vma if check_vma is not None else check_rep
-    if check is not None:
-        kwargs[_SHARD_MAP_KWARG] = check
-    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
 DEFAULT_RULES: Dict[str, Axis] = {
     "batch": "data",
     "seq": None,

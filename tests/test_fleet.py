@@ -390,3 +390,58 @@ def test_default_accuracy_unchanged():
     assert [e.cuts for e in res_default.pareto] == \
            [e.cuts for e in res_explicit.pareto]
     assert isinstance(ProxyAccuracy([], TWO_PLATFORM.build()), ProxyAccuracy)
+
+
+# -- one worker per accelerator host -------------------------------------------
+
+def _fake_popen(started):
+    class FakePopen:
+        def __init__(self, cmd, env=None):
+            started.append(cmd)
+    return FakePopen
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", 3), ("tpu", 1)])
+def test_start_workers_one_per_accelerator_host(tmp_path, monkeypatch,
+                                                backend, want):
+    """Every JAX process takes all of its host's chips, so on an
+    accelerator the launcher starts a single worker; on the CPU it starts
+    as many as asked."""
+    import warnings
+
+    import repro.fleet.launch as L
+    started = []
+    monkeypatch.setattr(L, "worker_backend", lambda env: backend)
+    monkeypatch.setattr(L.subprocess, "Popen", _fake_popen(started))
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        L.start_workers(str(tmp_path), 3)
+    assert len(started) == want
+    assert bool(caught) == (want == 1)
+
+
+def test_worker_backend_skips_probe_on_cpu(monkeypatch):
+    import repro.fleet.launch as L
+
+    def no_probe(*a, **kw):
+        raise AssertionError("probed although JAX_PLATFORMS=cpu")
+    monkeypatch.setattr(L.subprocess, "run", no_probe)
+    assert L.worker_backend({"JAX_PLATFORMS": "cpu"}) == "cpu"
+
+
+def test_launcher_imports_initialise_no_backend():
+    """The fleet parent imports the campaign and launcher modules before it
+    starts workers; importing them must not claim a device."""
+    import subprocess
+    import sys
+    code = ("import repro.explore.campaign, repro.fleet.launch, "
+            "repro.fleet.__main__\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n"
+            "print('NO_BACKEND')")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert "NO_BACKEND" in out.stdout

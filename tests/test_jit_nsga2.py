@@ -257,6 +257,17 @@ def test_spec_roundtrip_scaling_knobs():
         SearchSettings(n_restarts=0)
 
 
+def test_rank_devices_beyond_visible_raises():
+    """A search asked to shard its ranking over more devices than exist
+    fails instead of quietly running on fewer."""
+    import jax
+
+    from repro.explore.strategies import _rank_mesh
+    assert _rank_mesh(None) is None and _rank_mesh(1) is None
+    with pytest.raises(ValueError, match="rank_devices"):
+        _rank_mesh(len(jax.devices()) + 1)
+
+
 def test_jit_strategy_restarts_front_superset(evaluator):
     """n_restarts=2 merges both seeds' fronts: every single-seed front
     point is matched or dominated, and n_evaluated counts both runs."""

@@ -44,11 +44,23 @@ def test_quant_matmul_blocks():
 
 
 def test_quant_matmul_ops_fallback():
-    # off-grid shape falls back to the oracle silently
+    # an off-grid shape never falls back to the oracle silently: the pallas
+    # request raises, and the reference runs only when asked for
     x = jnp.ones((100, 96))
     w_q = jnp.ones((96, 50), jnp.int8)
-    y = ops.quant_matmul(x, w_q, jnp.ones((50,)), jnp.asarray(0.1))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ops.quant_matmul(x, w_q, jnp.ones((50,)), jnp.asarray(0.1))
+    y = ops.quant_matmul(x, w_q, jnp.ones((50,)), jnp.asarray(0.1),
+                         impl="ref")
     assert y.shape == (100, 50)
+
+
+def test_window_attn_ops_off_grid_raises():
+    q = jnp.ones((1, 96, 4, 32))
+    k = v = jnp.ones((1, 96, 2, 32))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ops.window_attn(q, k, v, window=64)
+    assert ops.window_attn(q, k, v, window=64, impl="ref").shape == q.shape
 
 
 # -- ssd_scan --------------------------------------------------------------------

@@ -21,8 +21,11 @@ from repro.core.nsga2 import pareto_indices  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 
 IMPLS = ("ref", "pallas")
-# deliberately ragged vs the 32/64-row tiles used below
-SIZES = (33, 97, 130)
+# deliberately ragged vs every tile: the ref twins tile by the 32/64 blocks
+# passed below, the Pallas kernels by 256 rows x 256 columns whatever the
+# block (kernels.ops), so 600 and 777 give them 3x3 and 4x4 grids: the
+# packed-output index map and the counts' accumulation across row steps
+SIZES = (33, 97, 130, 600, 777)
 
 
 def population(n, m=3, infeas=0.3, dup=False, seed=0):
