@@ -88,15 +88,46 @@ def nondominated_rank(F: Array, CV: Array,
     form instead of one O(n²/8) popcount pass per (often singleton) front.
     Ranks are bit-identical to the dense path; ``mesh`` (1-D) shards the
     tile rows across devices.
+
+    Inside a program the phases carry the scopes ``pack`` (the domination
+    words), ``peel`` (the popcount loop) and ``tail`` (the tiled path's
+    infeasible ranks).
     """
+    return _rank_and_passes(F, CV, cap, rank_block, rank_impl, mesh)[0]
+
+
+def _rank_and_passes(F: Array, CV: Array, cap: Optional[int],
+                     rank_block: Optional[int], rank_impl: str,
+                     mesh) -> Tuple[Array, Array]:
+    """:func:`nondominated_rank` and the number of peeling passes it ran
+    (int32: iterations of the popcount loop)."""
     n = F.shape[0]
     cap = n if cap is None else min(cap, n)
-    if rank_block:
-        return _rank_blocked(F, CV, cap, rank_block, rank_impl, mesh)
-    Dp = _pack_bits(domination_matrix(F, CV))       # (W, n) uint32
-    state = (jnp.full(n, n, dtype=jnp.int32),       # rank
-             jnp.ones(n, dtype=bool),               # alive (unranked)
-             jnp.int32(0), jnp.int32(0))            # front idx, ranked count
+    with jax.named_scope("pack"):
+        if rank_block:
+            from repro.kernels import ops
+            Dp = ops.packed_domination(F, CV, block=rank_block,
+                                       impl=rank_impl, mesh=mesh)
+            # only feasible layers are peeled; the infeasible tail follows
+            alive = CV <= 0
+        else:
+            Dp = _pack_bits(domination_matrix(F, CV))   # (W, n) uint32
+            alive = jnp.ones(n, dtype=bool)
+    with jax.named_scope("peel"):
+        rank, passes, done = _peel(Dp, alive, cap)
+    if not rank_block:
+        return rank, passes
+    with jax.named_scope("tail"):
+        return _infeasible_tail(CV, rank, passes, done, cap), passes
+
+
+def _peel(Dp: Array, alive: Array, cap: int) -> Tuple[Array, Array, Array]:
+    """Peel fronts of the ``alive`` individuals off the packed domination
+    words ``Dp`` until ``cap`` are ranked; returns (rank, passes, ranked
+    count), unranked individuals keeping rank ``n``."""
+    n = Dp.shape[1]
+    state = (jnp.full(n, n, dtype=jnp.int32), alive,
+             jnp.int32(0), jnp.int32(0))    # rank, alive, front idx, ranked
 
     def cond(s):
         _, alive, _, done = s
@@ -112,39 +143,20 @@ def nondominated_rank(F: Array, CV: Array,
         return (rank, alive & ~front, r + 1,
                 done + front.sum(dtype=jnp.int32))
 
-    rank, _, _, _ = lax.while_loop(cond, body, state)
-    return rank
+    rank, _, passes, done = lax.while_loop(cond, body, state)
+    return rank, passes, done
 
 
-def _rank_blocked(F: Array, CV: Array, cap: int, block: int, impl: str,
-                  mesh) -> Array:
-    """Tiled non-dominated ranking; see :func:`nondominated_rank`."""
-    from repro.kernels import ops
-    n = F.shape[0]
-    Dp = ops.packed_domination(F, CV, block=block, impl=impl, mesh=mesh)
+def _infeasible_tail(CV: Array, rank: Array, n_feas_fronts: Array,
+                     done: Array, cap: int) -> Array:
+    """Ranks of the tiled path's infeasible individuals after its feasible
+    layers (see :func:`nondominated_rank`)."""
+    n = CV.shape[0]
     feas = CV <= 0
-    state = (jnp.full(n, n, dtype=jnp.int32), feas,
-             jnp.int32(0), jnp.int32(0))
-
-    def cond(s):
-        _, alive, _, done = s
-        return alive.any() & (done < cap)
-
-    def body(s):
-        rank, alive, r, done = s
-        alive_p = _pack_bits(alive[:, None])[:, 0]
-        n_dom = lax.population_count(Dp & alive_p[:, None]).sum(axis=0)
-        front = alive & (n_dom == 0)
-        front = jnp.where(front.any(), front, alive)   # numerical safety
-        rank = jnp.where(front, r, rank)
-        return (rank, alive & ~front, r + 1,
-                done + front.sum(dtype=jnp.int32))
-
-    rank, _, n_feas_fronts, done = lax.while_loop(cond, body, state)
-    # infeasible tail: every feasible individual dominates every infeasible
-    # one and infeasible pairs compare by violation alone, so the remaining
-    # fronts are the equal-CV groups in ascending order.  A group is peeled
-    # iff the count ranked before it is still under the cap — exactly the
+    # every feasible individual dominates every infeasible one and
+    # infeasible pairs compare by violation alone, so the remaining fronts
+    # are the equal-CV groups in ascending order.  A group is peeled iff
+    # the count ranked before it is still under the cap — exactly the
     # dense loop's stopping rule.
     cvs = jnp.where(feas, jnp.inf, CV)
     order = jnp.argsort(cvs)
@@ -250,6 +262,10 @@ def make_offspring(key: Array, X: Array, F: Array, CV: Array, crowd: Array,
 
 # -- the compiled generation loop ---------------------------------------------
 
+# what the compiled loop counts, in the order it carries them: generations
+# executed, and iterations of the ``rank/peel`` popcount loop summed over them
+COUNTS = ("generations", "peel_passes")
+
 # auto rank_block policy: combined (2·pop) populations at/below the
 # threshold keep the dense packed path (fastest there, memory irrelevant);
 # beyond it the tiled path runs with the default tile rows
@@ -274,36 +290,49 @@ def _make_run(eval_fn: EvalFn, lo: int, hi: int, pop_size: int,
     every ``eval_fn(X, *eval_args)`` call — that is how runtime-valued
     evaluation tables (gene table, :class:`~repro.core.partition_jax
     .EvalTables`) flow through the compiled program without being baked
-    into the trace."""
+    into the trace.
+
+    Each phase runs under a ``jax.named_scope`` (``init``, then per
+    generation ``offspring``, ``evaluate``, ``rank/pack``, ``rank/peel``,
+    ``rank/tail``, ``crowding``, ``select``), which a device trace shows as
+    the path of every operation.  Beside (X, F, CV) the program returns
+    its :data:`counts <COUNTS>`: generations run and peeling passes summed
+    over them."""
 
     def gen_step(carry, eval_args):
-        key, X, F, CV, crowd = carry
+        key, X, F, CV, crowd, gens, peels = carry
         key, k_off = jax.random.split(key)
-        Xc = make_offspring(k_off, X, F, CV, crowd, lo, hi)
-        Fc, CVc = eval_fn(Xc, *eval_args)
+        with jax.named_scope("offspring"):
+            Xc = make_offspring(k_off, X, F, CV, crowd, lo, hi)
+        with jax.named_scope("evaluate"):
+            Fc, CVc = eval_fn(Xc, *eval_args)
         Xall = jnp.concatenate([X, Xc])
         Fall = jnp.concatenate([F, Fc])
         CVall = jnp.concatenate([CV, CVc])
         # elitist environmental selection: whole fronts in rank order, the
         # boundary front tie-broken by crowding == lexsort by (rank, -crowd)
-        rank = nondominated_rank(Fall, CVall, cap=pop_size,
-                                 rank_block=rank_block, rank_impl=rank_impl,
-                                 mesh=mesh)
-        crowd_all = crowding_by_rank(Fall, rank)
-        keep = jnp.lexsort((-crowd_all, rank))[:pop_size]
-        return key, Xall[keep], Fall[keep], CVall[keep], crowd_all[keep]
+        with jax.named_scope("rank"):
+            rank, passes = _rank_and_passes(Fall, CVall, pop_size,
+                                            rank_block, rank_impl, mesh)
+        with jax.named_scope("crowding"):
+            crowd_all = crowding_by_rank(Fall, rank)
+        with jax.named_scope("select"):
+            keep = jnp.lexsort((-crowd_all, rank))[:pop_size]
+            X, F, CV = Xall[keep], Fall[keep], CVall[keep]
+        return key, X, F, CV, crowd_all[keep], gens + 1, peels + passes
 
-    def run(key: Array, X0: Array, n_gen,
-            *eval_args) -> Tuple[Array, Array, Array]:
-        X0 = repair(X0, lo, hi)
-        F0, CV0 = eval_fn(X0, *eval_args)
-        rank0 = nondominated_rank(F0, CV0, rank_block=rank_block,
-                                  rank_impl=rank_impl, mesh=mesh)
-        crowd0 = crowding_by_rank(F0, rank0)
-        carry = (key, X0, F0, CV0, crowd0)
+    def run(key: Array, X0: Array, n_gen, *eval_args):
+        with jax.named_scope("init"):
+            X0 = repair(X0, lo, hi)
+            F0, CV0 = eval_fn(X0, *eval_args)
+            rank0 = nondominated_rank(F0, CV0, rank_block=rank_block,
+                                      rank_impl=rank_impl, mesh=mesh)
+            crowd0 = crowding_by_rank(F0, rank0)
+        carry = (key, X0, F0, CV0, crowd0, jnp.int32(0), jnp.int32(0))
         carry = lax.fori_loop(0, n_gen,
                               lambda _, c: gen_step(c, eval_args), carry)
-        return carry[1], carry[2], carry[3]
+        counts = dict(zip(COUNTS, carry[5:]))
+        return carry[1], carry[2], carry[3], counts
 
     return run
 
@@ -313,9 +342,10 @@ def make_jit_runner(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
                     rank_impl: str = "auto", mesh=None):
     """Compile the whole NSGA-II run into one XLA program.
 
-    Returns ``run(key, X0, n_gen, *eval_args) -> (X, F, CV)``; ``n_gen`` is
-    a traced loop bound, so one compilation serves any generation budget at
-    a given (pop_size, n_var) shape.  ``X0`` is donated — the population
+    Returns ``run(key, X0, n_gen, *eval_args) -> (X, F, CV, counts)``, where
+    ``counts`` maps each name of :data:`COUNTS` to an int32 scalar.
+    ``n_gen`` is a traced loop bound, so one compilation serves any
+    generation budget at a given (pop_size, n_var) shape.  ``X0`` is donated — the population
     buffers live in place across the generation loop.  Trailing
     ``eval_args`` are forwarded to ``eval_fn(X, *eval_args)`` as ordinary
     (non-donated) runtime arguments: pass value-bearing tables (gene table,
@@ -341,7 +371,7 @@ def make_jit_restart_runner(eval_fn: EvalFn, n_var: int, lower: int,
     """The ``vmap``-over-seeds twin of :func:`make_jit_runner`.
 
     Returns ``run(keys, X0s, n_gen, *eval_args)`` over arrays with a
-    leading restart axis — one compilation covers every generation budget
+    leading restart axis (the counts too: one per restart) — one compilation covers every generation budget
     at a given (n_restarts, pop_size, n_var) shape, and all restarts
     advance in lockstep inside a single XLA program.  ``n_eval_args``
     declares how many trailing runtime arguments ``eval_fn`` takes; they
@@ -354,10 +384,10 @@ def make_jit_restart_runner(eval_fn: EvalFn, n_var: int, lower: int,
     return jax.jit(jax.vmap(run, in_axes=axes), donate_argnums=(1,))
 
 
-def _init_population(rng: np.random.Generator, pop_size: int, n_var: int,
-                     lower: int, upper: int,
-                     candidates: Optional[Sequence[Sequence[int]]]
-                     ) -> np.ndarray:
+def init_population(rng: np.random.Generator, pop_size: int, n_var: int,
+                    lower: int, upper: int,
+                    candidates: Optional[Sequence[Sequence[int]]]
+                    ) -> np.ndarray:
     """Host-side population init — matches the NumPy
     :func:`repro.core.nsga2.nsga2` draw-for-draw."""
     X0 = rng.integers(lower, upper + 1, size=(pop_size, n_var))
@@ -407,7 +437,7 @@ def jit_nsga2(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
               pop_size: int, n_gen: int, seed: int = 0,
               candidates: Optional[Sequence[Sequence[int]]] = None,
               runner=None, X0: Optional[np.ndarray] = None,
-              eval_args: Tuple = ()
+              eval_args: Tuple = (), counts: Optional[dict] = None
               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Run the compiled NSGA-II loop; returns host (X, F, CV) arrays.
 
@@ -417,15 +447,18 @@ def jit_nsga2(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
     ``runner`` (from :func:`make_jit_runner`) to reuse a compilation, an
     explicit ``X0`` (pop_size, n_var) to override the uniform init (warm
     starts — see :func:`warm_population`), and ``eval_args`` to forward
-    runtime table values to ``eval_fn``.
+    runtime table values to ``eval_fn``.  A ``counts`` dict is filled with
+    the program's :data:`COUNTS` as ints.
     """
     if X0 is None:
-        X0 = _init_population(np.random.default_rng(seed), pop_size, n_var,
-                              lower, upper, candidates)
+        X0 = init_population(np.random.default_rng(seed), pop_size, n_var,
+                             lower, upper, candidates)
     if runner is None:
         runner = make_jit_runner(eval_fn, n_var, lower, upper, pop_size)
-    X, F, CV = runner(jax.random.PRNGKey(seed),
-                      jnp.asarray(X0, dtype=jnp.int32), n_gen, *eval_args)
+    X, F, CV, c = runner(jax.random.PRNGKey(seed),
+                         jnp.asarray(X0, dtype=jnp.int32), n_gen, *eval_args)
+    if counts is not None:
+        counts.update({k: int(v) for k, v in c.items()})
     return (np.asarray(X, dtype=np.int64), np.asarray(F, dtype=np.float64),
             np.asarray(CV, dtype=np.float64))
 
@@ -435,7 +468,7 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
                        seed: int = 0,
                        candidates: Optional[Sequence[Sequence[int]]] = None,
                        runner=None, X0s: Optional[np.ndarray] = None,
-                       eval_args: Tuple = ()
+                       eval_args: Tuple = (), counts: Optional[dict] = None
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Multi-restart search: ``n_restarts`` independently seeded runs as one
     vmapped XLA program, compiled once.
@@ -447,12 +480,13 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
     restart axis flattened to ``n_restarts * pop_size`` rows.  ``X0s``
     overrides the per-restart init (shape (n_restarts, pop_size, n_var));
     ``eval_args`` are broadcast to every restart (the runner must have been
-    built with a matching ``n_eval_args``).
+    built with a matching ``n_eval_args``).  A ``counts`` dict is filled
+    with the program's :data:`COUNTS`, each a list of one int per restart.
     """
     if X0s is None:
         X0s = np.stack([
-            _init_population(np.random.default_rng(seed + i), pop_size,
-                             n_var, lower, upper, candidates)
+            init_population(np.random.default_rng(seed + i), pop_size,
+                            n_var, lower, upper, candidates)
             for i in range(n_restarts)])
     keys = jnp.stack([jax.random.PRNGKey(seed + i)
                       for i in range(n_restarts)])
@@ -460,8 +494,10 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
         runner = make_jit_restart_runner(eval_fn, n_var, lower, upper,
                                          pop_size,
                                          n_eval_args=len(eval_args))
-    X, F, CV = runner(keys, jnp.asarray(X0s, dtype=jnp.int32), n_gen,
-                      *eval_args)
+    X, F, CV, c = runner(keys, jnp.asarray(X0s, dtype=jnp.int32), n_gen,
+                         *eval_args)
+    if counts is not None:
+        counts.update({k: np.asarray(v).tolist() for k, v in c.items()})
     flat = n_restarts * pop_size
     return (np.asarray(X, dtype=np.int64).reshape(flat, n_var),
             np.asarray(F, dtype=np.float64).reshape(flat, -1),

@@ -56,7 +56,7 @@ PUBLIC_API: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
         "DriftSignal",
     )),
     ("repro.obs", (
-        "Obs", "Tracer", "NullTracer", "Span", "MetricsRegistry",
+        "Obs", "phase", "Tracer", "NullTracer", "Span", "MetricsRegistry",
         "Counter", "Gauge", "Histogram", "default_registry",
         "to_chrome_trace", "write_chrome_trace", "load_chrome_trace",
         "validate_chrome_trace", "percentile", "latency_summary",

@@ -48,7 +48,7 @@ from repro.explore.filters import candidate_positions
 from repro.explore.result import ExplorationResult
 from repro.explore.runner import run_search
 from repro.explore.spec import ExplorationSpec, SearchSettings, SystemSpec
-from repro.obs.handle import NOOP_OBS, Obs
+from repro.obs.handle import NOOP_OBS, Obs, phase
 
 SystemLike = Union[SystemSpec, SystemConfig]
 
@@ -198,18 +198,29 @@ class OnlineRepartitioner:
         It must be same-shape with the baseline (same platform/link
         counts); a different shape still works but pays one fresh XLA
         compilation.
+
+        The whole call is the span ``search/entry`` (:func:`repro.obs
+        .phase`): the evaluator build ``search/evaluator``, the search's
+        own phases (:func:`~repro.explore.runner.run_search`), then the
+        carried warm front ``search/warm_carry``.
         """
+        with phase("search/entry", self.obs):
+            return self._update(system, label, trigger)
+
+    def _update(self, system: SystemLike, label: Optional[str],
+                trigger: str) -> RepartitionDecision:
         t0 = time.perf_counter()
-        if isinstance(system, SystemSpec):
-            label = label or system.label
-            system = system.build()
+        with phase("search/evaluator", self.obs):
+            if isinstance(system, SystemSpec):
+                label = label or system.label
+                system = system.build()
+            evaluator = self._evaluator(system)
         label = label or f"step{len(self.decisions)}"
-        evaluator = self._evaluator(system)
         res = run_search(
             evaluator, constraints=self.spec.constraints,
             objectives=self.spec.objectives, weights=self.spec.weights,
             settings=self.settings, candidates=self.candidates,
-            warm_cuts=self._front_cuts)
+            warm_cuts=self._front_cuts, obs=self.obs)
         ms = (time.perf_counter() - t0) * 1e3
         cuts = res.selected.cuts if res.selected is not None else None
         feasible = res.selected is not None and res.selected.violation <= 0
@@ -229,20 +240,21 @@ class OnlineRepartitioner:
             if decision.changed:
                 self.obs.metrics.counter("repartition_changes").inc()
             self.obs.metrics.histogram("repartition_ms").observe(ms)
-        if res.pareto:
-            front = res.pareto
-            if len(front) > self.max_warm_front:
-                # bound the carried warm seed: long drift histories must
-                # not grow it without limit, and crowding distance keeps
-                # the most diversity-preserving top-k of the front
-                F = np.asarray([e.as_objectives(self.spec.objectives)
-                                for e in front], dtype=float)
-                cd = crowding_distance(F)
-                keep = sorted(np.argsort(-cd, kind="stable")
-                              [:self.max_warm_front])
-                front = [front[int(i)] for i in keep]
-            self._front_cuts = np.asarray([e.cuts for e in front],
-                                          dtype=int)
+        with phase("search/warm_carry", self.obs):
+            if res.pareto:
+                front = res.pareto
+                if len(front) > self.max_warm_front:
+                    # bound the carried warm seed: long drift histories must
+                    # not grow it without limit, and crowding distance keeps
+                    # the most diversity-preserving top-k of the front
+                    F = np.asarray([e.as_objectives(self.spec.objectives)
+                                    for e in front], dtype=float)
+                    cd = crowding_distance(F)
+                    keep = sorted(np.argsort(-cd, kind="stable")
+                                  [:self.max_warm_front])
+                    front = [front[int(i)] for i in keep]
+                self._front_cuts = np.asarray([e.cuts for e in front],
+                                              dtype=int)
         self.decisions.append(decision)
         return decision
 

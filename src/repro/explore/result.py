@@ -62,6 +62,10 @@ class ExplorationResult:
     #                               differs from `strategy` on documented
     #                               downgrades (jit_nsga2 measured-accuracy
     #                               fallback) and for the "auto" policy
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
+    #                               the compiled loop's own counts, summed
+    #                               over restarts (jit_nsga2: generations,
+    #                               peel_passes); empty for other strategies
 
     def layer_name(self, cut: int) -> str:
         """Layer name at a cut position; ``"-"`` for the ``-1`` / out-of-

@@ -34,6 +34,7 @@ from repro.explore.filters import candidate_positions, link_feasibility
 from repro.explore.result import ExplorationResult
 from repro.explore.spec import AccuracySpec, ExplorationSpec, SearchSettings
 from repro.explore.strategies import (SearchContext, resolve_strategies)
+from repro.obs.handle import Obs, phase
 
 DEFAULT_OBJECTIVES = ("latency", "energy")
 
@@ -58,7 +59,8 @@ def run_search(evaluator: PartitionEvaluator, *,
                weights: Optional[Sequence[float]] = None,
                settings: Optional[SearchSettings] = None,
                candidates: Optional[Sequence[int]] = None,
-               warm_cuts: Optional[Sequence[Sequence[int]]] = None
+               warm_cuts: Optional[Sequence[Sequence[int]]] = None,
+               obs: Optional[Obs] = None
                ) -> ExplorationResult:
     """Run the configured strategies over a prebuilt evaluator and finish:
     union pool → final non-dominated filter → Def.-2 selection.
@@ -69,33 +71,38 @@ def run_search(evaluator: PartitionEvaluator, *,
     drifted systems; feasibility shifts are then absorbed by constraint
     domination instead of by re-filtering.  ``warm_cuts`` feeds a previous
     Pareto front's cut rows to warm-startable strategies (honored when
-    ``settings.warm_start`` is on).
+    ``settings.warm_start`` is on).  Its host phases are named spans
+    (:func:`repro.obs.phase`), also recorded on ``obs`` when it is live.
     """
     constraints = constraints or Constraints()
     settings = settings or SearchSettings()
     objectives = tuple(objectives)
     weights = (tuple(weights) if weights
                else tuple(1.0 for _ in objectives))
-    if candidates is None:
-        cands = candidate_positions(evaluator, constraints,
-                                    settings.allow_multi_tensor_cuts)
-    else:
-        cands = list(candidates)
+    with phase("search/candidates", obs):
+        if candidates is None:
+            cands = candidate_positions(evaluator, constraints,
+                                        settings.allow_multi_tensor_cuts)
+        else:
+            cands = list(candidates)
+        link_feas = link_feasibility(evaluator, constraints.max_link_bytes)
     ctx = SearchContext(
         evaluator=evaluator, candidates=cands, constraints=constraints,
-        objectives=objectives, settings=settings,
-        link_feas=link_feasibility(evaluator, constraints.max_link_bytes),
+        objectives=objectives, settings=settings, link_feas=link_feas,
         warm_cuts=(np.asarray(warm_cuts, dtype=int)
-                   if warm_cuts is not None and len(warm_cuts) else None))
+                   if warm_cuts is not None and len(warm_cuts) else None),
+        obs=obs or Obs.off())
 
-    baselines = [single_platform_eval(evaluator, i, constraints)
-                 for i in range(len(evaluator.system.platforms))]
+    with phase("search/baselines", obs):
+        baselines = [single_platform_eval(evaluator, i, constraints)
+                     for i in range(len(evaluator.system.platforms))]
 
     scan_pool: List[PartitionEval] = []
     search_pool: List[PartitionEval] = []
     all_evals: List[PartitionEval] = []
     nsga = None
     n_evaluated = 0
+    counts: Dict[str, int] = {}
     used: List[str] = []
     for strategy in resolve_strategies(settings, ctx.n_cuts, len(cands)):
         out = strategy.search(ctx)
@@ -104,6 +111,8 @@ def run_search(evaluator: PartitionEvaluator, *,
             all_evals = out.all_evals
         nsga = out.nsga or nsga
         n_evaluated += out.n_evaluated
+        for k, v in out.counts.items():
+            counts[k] = counts.get(k, 0) + v
         used.append(out.strategy_used or strategy.name)
 
     # pool order mirrors the legacy Explorer: exact scans, then feasible
@@ -113,23 +122,24 @@ def run_search(evaluator: PartitionEvaluator, *,
         pool = baselines[:]
 
     pareto: List[PartitionEval] = []
-    if pool:
-        F = np.array([ev.as_objectives(objectives) for ev in pool])
-        CV = np.array([ev.violation for ev in pool])
-        fronts = fast_non_dominated_sort(F, CV)
-        seen = set()
-        for i in fronts[0]:
-            if pool[i].cuts not in seen:
-                seen.add(pool[i].cuts)
-                pareto.append(pool[i])
-
-    selected = select_weighted(pareto, objectives, weights)
+    with phase("search/select", obs):
+        if pool:
+            F = np.array([ev.as_objectives(objectives) for ev in pool])
+            CV = np.array([ev.violation for ev in pool])
+            fronts = fast_non_dominated_sort(F, CV)
+            seen = set()
+            for i in fronts[0]:
+                if pool[i].cuts not in seen:
+                    seen.add(pool[i].cuts)
+                    pareto.append(pool[i])
+        selected = select_weighted(pareto, objectives, weights)
     return ExplorationResult(
         schedule=list(evaluator.schedule), candidates=cands,
         all_evals=all_evals, pareto=pareto, selected=selected,
         baselines=baselines, objectives=objectives, nsga=nsga,
         strategy=settings.strategy, n_evaluated=n_evaluated,
-        strategy_used="+".join(dict.fromkeys(used)) or settings.strategy)
+        strategy_used="+".join(dict.fromkeys(used)) or settings.strategy,
+        counts=counts)
 
 
 def explore_graph(graph: LayerGraph, system: SystemConfig, *,
@@ -153,22 +163,25 @@ def explore_graph(graph: LayerGraph, system: SystemConfig, *,
     accuracy oracle resolves in precedence order: a live ``accuracy_fn``
     object, then a declarative ``accuracy`` :class:`AccuracySpec` (proxy
     knobs or a registered measured oracle), then the default
-    :class:`ProxyAccuracy`.
+    :class:`ProxyAccuracy`.  The whole call is the span ``search/entry``
+    (:func:`repro.obs.phase`), its evaluator build ``search/evaluator``.
     """
-    if schedule is None:
-        schedule = linearize(graph, schedule_policy)
-    acc = accuracy_fn
-    if acc is None and accuracy is not None:
-        acc = accuracy.build(graph, schedule, system)
-    if acc is None:
-        acc = ProxyAccuracy(schedule, system)
-    evaluator = PartitionEvaluator(
-        graph, schedule, system, accuracy_fn=acc, batch=batch,
-        shared_groups=shared_groups, cost_cache=cost_cache,
-        memtable=memtable)
-    return run_search(evaluator, constraints=constraints,
-                      objectives=objectives, weights=weights,
-                      settings=search)
+    with phase("search/entry"):
+        with phase("search/evaluator"):
+            if schedule is None:
+                schedule = linearize(graph, schedule_policy)
+            acc = accuracy_fn
+            if acc is None and accuracy is not None:
+                acc = accuracy.build(graph, schedule, system)
+            if acc is None:
+                acc = ProxyAccuracy(schedule, system)
+            evaluator = PartitionEvaluator(
+                graph, schedule, system, accuracy_fn=acc, batch=batch,
+                shared_groups=shared_groups, cost_cache=cost_cache,
+                memtable=memtable)
+        return run_search(evaluator, constraints=constraints,
+                          objectives=objectives, weights=weights,
+                          settings=search)
 
 
 def run_spec(spec: ExplorationSpec) -> ExplorationResult:

@@ -42,6 +42,7 @@ from repro.core.partition import (Constraints, PartitionEval,
                                   PartitionEvaluator)
 from repro.explore.filters import feasible_cut_rows
 from repro.explore.spec import SearchSettings
+from repro.obs.handle import Obs, phase
 from repro.obs.metrics import default_registry
 
 # full per-point scans are kept (for Fig.-2-style plots) only below this size
@@ -59,6 +60,8 @@ class SearchContext:
     settings: SearchSettings
     link_feas: Optional[np.ndarray] = None   # (n_links, L-1) or None
     warm_cuts: Optional[np.ndarray] = None   # (n, n_cuts) previous front
+    obs: Obs = dataclasses.field(default_factory=Obs.off)
+    #                                          also records the host phases
 
     @property
     def n_cuts(self) -> int:
@@ -83,6 +86,8 @@ class StrategyOutput:
     n_evaluated: int = 0       # candidate vectors actually scored
     strategy_used: str = ""    # actual strategy name when != the requested
     #                            one (e.g. jit_nsga2's NumPy fallback)
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
+    #                            the compiled loop's own counts (jit_nsga2)
 
 
 @runtime_checkable
@@ -383,7 +388,8 @@ class JitNSGA2Search:
 
         import jax.numpy as jnp
 
-        from repro.core.nsga2_jax import (jit_nsga2, jit_nsga2_restarts,
+        from repro.core.nsga2_jax import (COUNTS, init_population, jit_nsga2,
+                                          jit_nsga2_restarts,
                                           make_jit_restart_runner,
                                           make_jit_runner,
                                           pareto_indices_blocked,
@@ -392,10 +398,12 @@ class JitNSGA2Search:
 
         table = _gene_table(ctx)
         n_cuts = ctx.n_cuts
+        hi = len(table) - 1
         pop, n_gen = _pop_gen(ctx)
         n_restarts = settings.n_restarts
         mesh = _rank_mesh(settings.rank_devices)
-        tables = evaluator.jax_tables()
+        with phase("search/tables", ctx.obs):
+            tables = evaluator.jax_tables()
 
         # shared compiled-runner cache: the gene table and the evaluator
         # tables enter the program as runtime pytree arguments, so the key
@@ -407,12 +415,8 @@ class JitNSGA2Search:
                pop, n_cuts, len(table), settings.allow_multi_tensor_cuts,
                settings.rank_block, settings.rank_impl, n_restarts,
                settings.rank_devices)
-        reg = default_registry()
         t_search = time.perf_counter()
         runner = _JIT_RUNNER_CACHE.get(key)
-        fresh_runner = runner is None
-        reg.counter("search_jit_runner_cache_misses" if fresh_runner
-                    else "search_jit_runner_cache_hits").inc()
         if runner is None:
             eval_cuts = make_runtime_eval_fn(tables, ctx.objectives,
                                              ctx.constraints)
@@ -422,67 +426,67 @@ class JitNSGA2Search:
 
             if n_restarts > 1:
                 runner = make_jit_restart_runner(
-                    _eval_genes, n_var=n_cuts, lower=0,
-                    upper=len(table) - 1, pop_size=pop,
-                    rank_block=settings.rank_block,
+                    _eval_genes, n_var=n_cuts, lower=0, upper=hi,
+                    pop_size=pop, rank_block=settings.rank_block,
                     rank_impl=settings.rank_impl, mesh=mesh, n_eval_args=2)
             else:
                 runner = make_jit_runner(
-                    _eval_genes, n_var=n_cuts, lower=0,
-                    upper=len(table) - 1, pop_size=pop,
-                    rank_block=settings.rank_block,
+                    _eval_genes, n_var=n_cuts, lower=0, upper=hi,
+                    pop_size=pop, rank_block=settings.rank_block,
                     rank_impl=settings.rank_impl, mesh=mesh)
             _JIT_RUNNER_CACHE[key] = runner
         eval_args = (jnp.asarray(table), tables)
 
-        seeds = _gene_seeds(cands, table, n_cuts)
-        warm = _warm_genes(ctx, table)
-        if warm is not None:
-            reg.counter("search_warm_starts").inc()
-        if n_restarts > 1:
-            X0s = None
-            if warm is not None:
-                X0s = np.stack([
-                    warm_population(
-                        np.random.default_rng(settings.seed + i), pop,
-                        n_cuts, 0, len(table) - 1, warm)
-                    for i in range(n_restarts)])
-            X, F, CV = jit_nsga2_restarts(
-                None, n_var=n_cuts, lower=0, upper=len(table) - 1,
-                pop_size=pop, n_gen=n_gen, n_restarts=n_restarts,
-                seed=settings.seed, candidates=seeds, runner=runner,
-                X0s=X0s, eval_args=eval_args)
-        else:
-            X0 = None
-            if warm is not None:
-                X0 = warm_population(np.random.default_rng(settings.seed),
-                                     pop, n_cuts, 0, len(table) - 1, warm)
-            X, F, CV = jit_nsga2(
-                None, n_var=n_cuts, lower=0, upper=len(table) - 1,
-                pop_size=pop, n_gen=n_gen, seed=settings.seed,
-                candidates=seeds, runner=runner, X0=X0,
-                eval_args=eval_args)
-        search_s = time.perf_counter() - t_search
-        reg.histogram("search_wall_s").observe(search_s)
-        if fresh_runner:
-            # first call through a fresh runner pays the XLA compilation,
-            # so its wall is the compile-cost signal the drift loop watches
-            reg.histogram("search_jit_compile_s").observe(search_s)
-        if len(X) > self._DENSE_PARETO_MAX:
-            p_idx = pareto_indices_blocked(X, F, CV,
-                                           block=settings.rank_block or 2048,
-                                           impl=settings.rank_impl)
-        else:
-            p_idx = pareto_indices(X, F, CV)
+        with phase("search/init", ctx.obs):
+            warm = _warm_genes(ctx, table)
+            seeds = _gene_seeds(cands, table, n_cuts)
+
+            def _start(i: int) -> np.ndarray:
+                # restart i draws from seed + i, warm or cold
+                rng = np.random.default_rng(settings.seed + i)
+                if warm is not None:
+                    return warm_population(rng, pop, n_cuts, 0, hi, warm)
+                return init_population(rng, pop, n_cuts, 0, hi, seeds)
+
+            X0 = (np.stack([_start(i) for i in range(n_restarts)])
+                  if n_restarts > 1 else _start(0))
+        counts: Dict = {}
+        with phase("search/device", ctx.obs):
+            if n_restarts > 1:
+                X, F, CV = jit_nsga2_restarts(
+                    None, n_var=n_cuts, lower=0, upper=hi, pop_size=pop,
+                    n_gen=n_gen, n_restarts=n_restarts, seed=settings.seed,
+                    runner=runner, X0s=X0, eval_args=eval_args,
+                    counts=counts)
+            else:
+                X, F, CV = jit_nsga2(
+                    None, n_var=n_cuts, lower=0, upper=hi, pop_size=pop,
+                    n_gen=n_gen, seed=settings.seed, runner=runner, X0=X0,
+                    eval_args=eval_args, counts=counts)
+        default_registry().histogram("search_wall_s").observe(
+            time.perf_counter() - t_search)
+        # per restart -> summed over restarts
+        counts = {k: int(np.sum(counts[k])) for k in COUNTS}
+        with phase("search/front", ctx.obs):
+            if len(X) > self._DENSE_PARETO_MAX:
+                p_idx = pareto_indices_blocked(
+                    X, F, CV, block=settings.rank_block or 2048,
+                    impl=settings.rank_impl)
+            else:
+                p_idx = pareto_indices(X, F, CV)
         res = NSGA2Result(X=X, F=F, CV=CV, pareto_idx=p_idx, history=[])
         evals: List[PartitionEval] = []
-        if len(res.pareto_X):
-            evals = evaluator.evaluate_batch(
-                np.sort(table[res.pareto_X], axis=1),
-                ctx.constraints).to_evals()
-        return StrategyOutput(evals, nsga=res,
-                              n_evaluated=n_restarts * pop * (n_gen + 1),
-                              strategy_used=self.name)
+        with phase("search/rescore", ctx.obs):
+            if len(res.pareto_X):
+                evals = evaluator.evaluate_batch(
+                    np.sort(table[res.pareto_X], axis=1),
+                    ctx.constraints).to_evals()
+        # each restart evaluates its initial population and one offspring
+        # population per generation it ran
+        return StrategyOutput(
+            evals, nsga=res,
+            n_evaluated=pop * (n_restarts + counts["generations"]),
+            strategy_used=self.name, counts=counts)
 
 
 STRATEGIES: Dict[str, Type] = {

@@ -17,7 +17,8 @@ dense relation never exists in memory:
   place (the standard Pallas matmul accumulation pattern).  Peak memory is
   O(n · block).
 
-Both take the dominator rows and the column population separately so the
+Each ``pallas_call`` carries its function's name, which a device trace
+shows as the kernel's operation name.  Both take the dominator rows and the column population separately so the
 row space can be sharded across devices (``shard_map`` over row tiles in
 ``kernels.ops``).  The pure-jnp blocked twins live in ``kernels.ref``;
 ground truth for both is the dense ``nsga2_jax.domination_matrix``.
@@ -90,6 +91,7 @@ def packed_domination(f_rows: jnp.ndarray, cv_rows: jnp.ndarray,
         out_specs=pl.BlockSpec((bp // 32, bq), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r // 32, n), jnp.uint32),
         interpret=interpret,
+        name="packed_domination",
     )(f_rows.astype(jnp.float32),
       cv_rows.astype(jnp.float32).reshape(-1, 1), fqt, cvq)
 
@@ -134,6 +136,7 @@ def domination_counts(f_rows: jnp.ndarray, cv_rows: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bq), lambda i, p: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=interpret,
+        name="domination_counts",
     )(f_rows.astype(jnp.float32), cv_rows.astype(jnp.float32).reshape(-1, 1),
       alive_rows.astype(jnp.int32).reshape(-1, 1), fqt, cvq)
     return out[0]

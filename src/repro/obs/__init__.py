@@ -5,6 +5,8 @@ a unified metrics registry, and Chrome-trace export.
   ``PipelineServeEngine``, ``ReplicaRouter``, the health monitors and the
   launch drivers; disabled (:data:`NOOP_OBS`) by default, switched on with
   ``Obs.on()``.
+* :func:`phase` — a named stretch of host work, on the JAX profiler's
+  host plane (the device trace's clock) and on a live handle's tracer.
 * :class:`Tracer` / :class:`Span` — low-overhead, thread-safe span
   recording on monotonic clocks (:mod:`repro.obs.trace`).
 * :class:`MetricsRegistry` / :func:`default_registry` — counters, gauges,
@@ -17,14 +19,14 @@ a unified metrics registry, and Chrome-trace export.
 
 from repro.obs.chrome import (load_chrome_trace, to_chrome_trace,
                               validate_chrome_trace, write_chrome_trace)
-from repro.obs.handle import NOOP_OBS, Obs
+from repro.obs.handle import NOOP_OBS, Obs, phase
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                default_registry)
 from repro.obs.stats import latency_summary, mean_tail, percentile
 from repro.obs.trace import NullTracer, Span, Tracer
 
 __all__ = [
-    "Obs", "NOOP_OBS", "Tracer", "NullTracer", "Span",
+    "Obs", "NOOP_OBS", "phase", "Tracer", "NullTracer", "Span",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "default_registry",
     "to_chrome_trace", "write_chrome_trace", "load_chrome_trace",
     "validate_chrome_trace",
