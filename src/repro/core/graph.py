@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Set, Tuple
 
 from repro.core.layers import LayerInfo
 
@@ -122,49 +122,98 @@ class LayerGraph:
         return order
 
     # -- cut analysis --------------------------------------------------------
+    def _live_spans(self, schedule: Sequence[LayerInfo]) -> Dict[str, Tuple[int, int]]:
+        """Producer -> ``(first, last)``: it is live across cut ``p`` exactly
+        when ``first <= p < last``.
+
+        One pass over the schedule and one over the edge list, O(L + E).
+        ``first`` is the producer's schedule position (its first, should a
+        name repeat) and ``last`` its latest consumer's; a consumer missing
+        from the schedule never runs and counts as ``len(schedule)``, live
+        to the end.  Producers off the schedule, graph outputs (no
+        consumers) and empty spans are left out.
+        """
+        n = len(schedule)
+        pos: Dict[str, int] = {}
+        for i, layer in enumerate(schedule):
+            pos.setdefault(layer.name, i)
+        last: Dict[str, int] = {}
+        for u, v in self.edges:
+            if u in pos:
+                last[u] = max(last.get(u, -1), pos.get(v, n))
+        return {u: (pos[u], l) for u, l in last.items() if pos[u] < l}
+
+    def _live_sweep(self, schedule: Sequence[LayerInfo]) -> Iterator[Tuple[Set[str], int]]:
+        """Live names and their total elements across each cut ``p`` in
+        ``[0, L-1)``, in order, from one O(L + E) sweep of
+        :meth:`_live_spans`: each span enters the running set and sum at
+        ``first`` and leaves at ``last``.  The yielded set is the sweep's
+        own and changes on the next step; copy it to keep it."""
+        n = len(schedule)
+        enter: List[List[str]] = [[] for _ in range(n + 1)]
+        leave: List[List[str]] = [[] for _ in range(n + 1)]
+        for u, (first, last) in self._live_spans(schedule).items():
+            enter[first].append(u)
+            leave[last].append(u)
+        live: Set[str] = set()
+        elems = 0
+        for p in range(n - 1):
+            for u in enter[p]:
+                live.add(u)
+                elems += self.nodes[u].fmap_out
+            for u in leave[p]:
+                live.remove(u)
+                elems -= self.nodes[u].fmap_out
+            yield live, elems
+
     def live_set(self, schedule: Sequence[LayerInfo], p: int) -> List[str]:
         """Tensors live across the cut after position ``p`` (0-indexed).
 
-        A producer in the prefix is live if any consumer is in the suffix,
-        or if it is a graph output (no consumers at all) — graph outputs
-        are not transmitted, so they are excluded here.
+        Sorted by name.  A producer in the prefix ``schedule[:p+1]`` is
+        live if any consumer is outside it (a consumer missing from the
+        schedule included).  Graph outputs (no consumers at all) are not
+        transmitted, so they are excluded.  Read from :meth:`_live_spans`
+        in O(L + E), the same set a rescan of the prefix and each member's
+        successors gives.
         """
-        prefix = {l.name for l in schedule[: p + 1]}
-        live: List[str] = []
-        for name in prefix:
-            consumers = self.succs(name)
-            if any(c not in prefix for c in consumers):
-                live.append(name)
-        return sorted(live)
+        # the prefix schedule[:p+1] ends at position k - 1, negative p too
+        k = len(range(len(schedule))[: p + 1])
+        return sorted(u for u, (first, last) in self._live_spans(schedule).items()
+                      if first < k <= last)
 
     def clean_cuts(self, schedule: Sequence[LayerInfo]) -> List[int]:
         """Positions p where the live set is exactly {schedule[p].name}.
 
         These are the paper's Definition-1 partitioning points: one tensor
-        (f_p, the output of l_p) crosses the link.
+        (f_p, the output of l_p) crosses the link.  One O(L + E) sweep.
         """
-        cuts: List[int] = []
-        for p in range(len(schedule) - 1):
-            if self.live_set(schedule, p) == [schedule[p].name]:
-                cuts.append(p)
-        return cuts
+        return [p for p, (live, _) in enumerate(self._live_sweep(schedule))
+                if len(live) == 1 and schedule[p].name in live]
 
     def all_cuts(self, schedule: Sequence[LayerInfo],
                  max_live: int = 4) -> List[Tuple[int, List[str]]]:
-        """Beyond-paper: every position with |live set| <= max_live."""
-        out: List[Tuple[int, List[str]]] = []
-        for p in range(len(schedule) - 1):
-            live = self.live_set(schedule, p)
-            if 0 < len(live) <= max_live:
-                out.append((p, live))
-        return out
+        """Beyond-paper: every position with 0 < |live set| <= max_live.
+
+        Each with its sorted live names, from one O(L + E) sweep."""
+        return [(p, sorted(live))
+                for p, (live, _) in enumerate(self._live_sweep(schedule))
+                if 0 < len(live) <= max_live]
+
+    def cut_elements(self, schedule: Sequence[LayerInfo]) -> List[int]:
+        """Elements live across the cut at every position p in ``[0, L-1)``.
+
+        Exact integer sums of the live producers' ``fmap_out``, equal to
+        ``cut_bytes(schedule, p, 1.0)`` at every p, from one O(L + E)
+        sweep."""
+        return [elems for _, elems in self._live_sweep(schedule)]
 
     def cut_bytes(self, schedule: Sequence[LayerInfo], p: int,
                   bytes_per_elem: float) -> int:
         """Bytes transmitted over the link for a cut after position p.
 
         Sub-byte widths round up (a 4-bit link shipping one element still
-        moves a byte), matching the serving-side accounting."""
+        moves a byte), matching the serving-side accounting.  O(L + E) via
+        :meth:`live_set`; use :meth:`cut_elements` for every position."""
         live = self.live_set(schedule, p)
         total = sum(self.nodes[n].fmap_out for n in live)
         return int(math.ceil(total * bytes_per_elem))

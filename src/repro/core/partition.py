@@ -16,6 +16,7 @@ optimal (Table II).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -178,10 +179,9 @@ class PartitionEvaluator:
         self.shared_groups = shared_groups
         self._tables: Dict[str, List[LayerCost]] = {}
         self._prefix: Dict[str, np.ndarray] = {}
-        self._cut_bytes_cache: Dict[Tuple[int, float], int] = {}
         self._memtable = (memtable if memtable is not None
                           else SegmentMemoryTable(self.schedule, shared_groups))
-        self._cut_elems: Optional[np.ndarray] = None  # lazy, O(L·E) to build
+        self._cut_elems: Optional[np.ndarray] = None  # lazy, O(L + E) sweep
         self._jax_tables = None                       # lazy EvalTables export
         cache = cost_cache if cost_cache is not None else {}
         for plat in system.platforms:
@@ -209,23 +209,23 @@ class PartitionEvaluator:
         return float(pre[0, b + 1] - pre[0, a]), float(pre[1, b + 1] - pre[1, a])
 
     def _cut_bytes(self, p: int, bpe: float) -> int:
-        key = (p, bpe)
-        if key not in self._cut_bytes_cache:
-            self._cut_bytes_cache[key] = self.graph.cut_bytes(
-                self.schedule, p, bpe)
-        return self._cut_bytes_cache[key]
+        """``graph.cut_bytes(schedule, p, bpe)``, read from the element
+        vector: the same ceil of the same product."""
+        return int(math.ceil(self._cut_elems_vec()[p] * bpe))
 
     def _cut_elems_vec(self) -> np.ndarray:
         """Elements crossing the link for every cut position p in [0, L-1)."""
         if self._cut_elems is None:
-            self._cut_elems = np.array(
-                [self.graph.cut_bytes(self.schedule, p, 1.0)
-                 for p in range(len(self.schedule) - 1)], dtype=np.int64)
+            self._cut_elems = np.array(self.graph.cut_elements(self.schedule),
+                                       dtype=np.int64)
         return self._cut_elems
 
     def cut_elements(self) -> np.ndarray:
         """Public view of the per-position link element counts (length
-        L-1), used by the candidate filters' feasibility matrices."""
+        L-1, exact ``int64``), used by the candidate filters' feasibility
+        matrices and the device tables.  Built once per evaluator by
+        :meth:`LayerGraph.cut_elements`' O(L + E) sweep, equal at every p
+        to ``graph.cut_bytes(schedule, p, 1.0)``."""
         return self._cut_elems_vec()
 
     def jax_tables(self):

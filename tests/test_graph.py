@@ -1,10 +1,18 @@
 """Graph IR: topo sort, clean cuts, live sets, branch regions."""
 
+import functools
+import math
+import time
+
+import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import layers as L
 from repro.core.graph import GraphError, LayerGraph, linearize
+from repro.core.partition import PartitionEvaluator
+from repro.explore import PlatformSpec, SystemSpec
+from repro.models.cnn.zoo import CNN_ZOO, build_cnn
 
 
 def chain_graph(n=5):
@@ -116,3 +124,120 @@ def test_cut_bytes_nonnegative_and_zero_only_at_sinks(g):
     sched = g.topo_sort()
     for p in range(len(sched) - 1):
         assert g.cut_bytes(sched, p, 1.0) >= 0
+
+
+# -- the linear cut sweep against the prefix-set definition -------------------
+#
+# The reference is the definition itself: the prefix schedule[:p+1] as a set,
+# and a prefix producer is live when an edge of the edge list leads from it
+# out of the prefix.  It costs O(L * E) over all positions; the graph's sweep
+# must give the same answers in O(L + E).
+
+BPES = (0.5, 1.0, 2.0)          # 4-, 8- and 16-bit links
+
+
+def ref_live_set(g, schedule, p):
+    prefix = {l.name for l in schedule[: p + 1]}
+    return sorted({u for u, v in g.edges if u in prefix and v not in prefix})
+
+
+def ref_cut_bytes(g, schedule, p, bpe):
+    total = sum(g.nodes[n].fmap_out for n in ref_live_set(g, schedule, p))
+    return int(math.ceil(total * bpe))
+
+
+def assert_cuts_match_reference(g, schedule):
+    n = len(schedule)
+    lives = [ref_live_set(g, schedule, p) for p in range(n)]
+    assert g.clean_cuts(schedule) == [
+        p for p in range(n - 1) if lives[p] == [schedule[p].name]]
+    for max_live in (1, 4, n):
+        assert g.all_cuts(schedule, max_live) == [
+            (p, lives[p]) for p in range(n - 1) if 0 < len(lives[p]) <= max_live]
+    assert g.cut_elements(schedule) == [
+        ref_cut_bytes(g, schedule, p, 1.0) for p in range(n - 1)]
+    for p in range(n):
+        assert g.live_set(schedule, p) == lives[p]
+        for bpe in BPES:
+            assert g.cut_bytes(schedule, p, bpe) == ref_cut_bytes(g, schedule, p, bpe)
+
+
+@functools.lru_cache(maxsize=None)
+def zoo_graph(name):
+    return build_cnn(name).to_graph()
+
+
+@pytest.mark.parametrize("policy", ["insertion", "min_memory", "random"])
+@pytest.mark.parametrize("name", sorted(CNN_ZOO))
+def test_cut_sweep_matches_prefix_definition_on_zoo(name, policy):
+    g = zoo_graph(name)
+    assert_cuts_match_reference(g, linearize(g, policy, seed=11))
+
+
+@pytest.mark.parametrize("name", sorted(CNN_ZOO))
+def test_evaluator_cut_elements_match_prefix_definition(name):
+    g = zoo_graph(name)
+    schedule = linearize(g, "min_memory")
+    system = SystemSpec(platforms=(PlatformSpec("A0", "eyr", bits=16),
+                                   PlatformSpec("B0", "smb", bits=4)),
+                        links=("gige",)).build()
+    ev = PartitionEvaluator(g, schedule, system)
+    elems = ev.cut_elements()
+    assert elems.dtype == np.int64
+    assert elems.tolist() == [ref_cut_bytes(g, schedule, p, 1.0)
+                              for p in range(len(schedule) - 1)]
+    for p in range(len(schedule) - 1):
+        for bpe in BPES:
+            assert ev._cut_bytes(p, bpe) == ref_cut_bytes(g, schedule, p, bpe)
+
+
+@st.composite
+def random_fanout_dag(draw):
+    """Several sources, sinks anywhere (nodes nobody consumes) and fan-out
+    to many consumers; feature-map sizes differ so element sums do too."""
+    n = draw(st.integers(2, 16))
+    g = LayerGraph(name="fanout")
+    for i in range(n):
+        preds = draw(st.sets(st.integers(0, i - 1), max_size=4)) if i else set()
+        size = draw(st.integers(1, 9))
+        g.add(L.elementwise_layer(f"n{i}", L.RELU, (size, 3)),
+              after=[f"n{p}" for p in sorted(preds)] or None)
+    return g
+
+
+@given(random_fanout_dag(), st.integers(0, 10_000))
+@settings(max_examples=60, deadline=None)
+def test_cut_sweep_matches_prefix_definition_on_random_dags(g, seed):
+    for policy in ("insertion", "min_memory", "random"):
+        assert_cuts_match_reference(g, linearize(g, policy, seed=seed))
+
+
+def test_cut_sweep_on_a_partial_schedule():
+    """A consumer left off the schedule never runs, so its producer stays
+    live to the end, also past the schedule's last position; a name that
+    repeats joins the prefix at its first position."""
+    g = diamond_graph()
+    schedule = [g.nodes[n] for n in ("a", "b1", "c")]
+    assert_cuts_match_reference(g, schedule)
+    assert g.live_set(schedule, 2) == ["a", "c"]
+    assert g.live_set(schedule, -1) == []
+    assert_cuts_match_reference(g, [g.nodes[n] for n in ("a", "b1", "a", "b2", "c")])
+
+
+def test_cut_sweep_is_linear_on_a_long_chain():
+    """20,000 layers: the sweep takes a fraction of a second, a per-position
+    rescan of the prefix (quadratic or worse) takes minutes."""
+    n = 20_000
+    g = chain_graph(n)
+    schedule = g.topo_sort()
+    t0 = time.perf_counter()
+    clean = g.clean_cuts(schedule)
+    cuts = g.all_cuts(schedule)
+    elems = g.cut_elements(schedule)
+    live = g.live_set(schedule, n // 2)
+    wall = time.perf_counter() - t0
+    assert clean == list(range(n - 1))
+    assert [p for p, _ in cuts] == clean
+    assert elems == [4 * 8 * 8] * (n - 1)
+    assert live == [schedule[n // 2].name]
+    assert wall < 5.0, f"cut sweep of {n} layers took {wall:.2f} s"
