@@ -263,10 +263,16 @@ def nsga2(evaluate: Callable[[np.ndarray], Tuple[np.ndarray, np.ndarray]],
 
 def pareto_indices(X: np.ndarray, F: np.ndarray, CV: np.ndarray) -> np.ndarray:
     """Final-front extraction shared by the NumPy and JIT search paths:
-    first constrained front, feasible subset when non-empty, unique decision
-    vectors (first occurrence wins, ascending index order)."""
-    fronts = fast_non_dominated_sort(F, CV)
-    first = fronts[0]
+    the first constrained front, then :func:`front_selection`."""
+    return front_selection(X, CV, fast_non_dominated_sort(F, CV)[0])
+
+
+def front_selection(X: np.ndarray, CV: np.ndarray,
+                    first: np.ndarray) -> np.ndarray:
+    """The reported front from ``first``, the ascending indices of a
+    population's first constrained front: its feasible subset when
+    non-empty, then unique decision vectors (first occurrence wins,
+    ascending index order)."""
     feas = first[CV[first] <= 0]
     pareto = feas if len(feas) else first
     _, uniq = np.unique(X[pareto], axis=0, return_index=True)

@@ -262,9 +262,10 @@ def make_offspring(key: Array, X: Array, F: Array, CV: Array, crowd: Array,
 
 # -- the compiled generation loop ---------------------------------------------
 
-# what the compiled loop counts, in the order it carries them: generations
-# executed, and iterations of the ``rank/peel`` popcount loop summed over them
-COUNTS = ("generations", "peel_passes")
+# what the compiled loop counts: generations executed, iterations of the
+# ``rank/peel`` popcount loop summed over them, and the final population's
+# rows on its first front
+COUNTS = ("generations", "peel_passes", "front_rows")
 
 # auto rank_block policy: combined (2·pop) populations at/below the
 # threshold keep the dense packed path (fastest there, memory irrelevant);
@@ -296,11 +297,16 @@ def _make_run(eval_fn: EvalFn, lo: int, hi: int, pop_size: int,
     generation ``offspring``, ``evaluate``, ``rank/pack``, ``rank/peel``,
     ``rank/tail``, ``crowding``, ``select``), which a device trace shows as
     the path of every operation.  Beside (X, F, CV) the program returns
-    its :data:`counts <COUNTS>`: generations run and peeling passes summed
-    over them."""
+    the final population's first-front mask and its :data:`counts
+    <COUNTS>`.
+
+    The mask is the survivors' rank 0, carried from the ranking that
+    selected them: survivors are kept whole front by whole front, so a
+    survivor of rank k > 0 is dominated by a survivor of rank k - 1, and a
+    rank-0 one by none."""
 
     def gen_step(carry, eval_args):
-        key, X, F, CV, crowd, gens, peels = carry
+        key, X, F, CV, crowd, _, gens, peels = carry
         key, k_off = jax.random.split(key)
         with jax.named_scope("offspring"):
             Xc = make_offspring(k_off, X, F, CV, crowd, lo, hi)
@@ -319,7 +325,8 @@ def _make_run(eval_fn: EvalFn, lo: int, hi: int, pop_size: int,
         with jax.named_scope("select"):
             keep = jnp.lexsort((-crowd_all, rank))[:pop_size]
             X, F, CV = Xall[keep], Fall[keep], CVall[keep]
-        return key, X, F, CV, crowd_all[keep], gens + 1, peels + passes
+        return (key, X, F, CV, crowd_all[keep], rank[keep], gens + 1,
+                peels + passes)
 
     def run(key: Array, X0: Array, n_gen, *eval_args):
         with jax.named_scope("init"):
@@ -328,11 +335,14 @@ def _make_run(eval_fn: EvalFn, lo: int, hi: int, pop_size: int,
             rank0 = nondominated_rank(F0, CV0, rank_block=rank_block,
                                       rank_impl=rank_impl, mesh=mesh)
             crowd0 = crowding_by_rank(F0, rank0)
-        carry = (key, X0, F0, CV0, crowd0, jnp.int32(0), jnp.int32(0))
-        carry = lax.fori_loop(0, n_gen,
-                              lambda _, c: gen_step(c, eval_args), carry)
-        counts = dict(zip(COUNTS, carry[5:]))
-        return carry[1], carry[2], carry[3], counts
+        carry = (key, X0, F0, CV0, crowd0, rank0, jnp.int32(0),
+                 jnp.int32(0))
+        _, X, F, CV, _, rank, gens, peels = lax.fori_loop(
+            0, n_gen, lambda _, c: gen_step(c, eval_args), carry)
+        front = rank == 0
+        counts = dict(zip(COUNTS, (gens, peels,
+                                   front.sum(dtype=jnp.int32))))
+        return X, F, CV, front, counts
 
     return run
 
@@ -342,8 +352,10 @@ def make_jit_runner(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
                     rank_impl: str = "auto", mesh=None):
     """Compile the whole NSGA-II run into one XLA program.
 
-    Returns ``run(key, X0, n_gen, *eval_args) -> (X, F, CV, counts)``, where
-    ``counts`` maps each name of :data:`COUNTS` to an int32 scalar.
+    Returns ``run(key, X0, n_gen, *eval_args) -> (X, F, CV, front,
+    counts)``, where ``front`` is the (pop_size,) bool mask of the final
+    population's first front and ``counts`` maps each name of
+    :data:`COUNTS` to an int32 scalar.
     ``n_gen`` is a traced loop bound, so one compilation serves any
     generation budget at a given (pop_size, n_var) shape.  ``X0`` is donated — the population
     buffers live in place across the generation loop.  Trailing
@@ -437,7 +449,8 @@ def jit_nsga2(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
               pop_size: int, n_gen: int, seed: int = 0,
               candidates: Optional[Sequence[Sequence[int]]] = None,
               runner=None, X0: Optional[np.ndarray] = None,
-              eval_args: Tuple = (), counts: Optional[dict] = None
+              eval_args: Tuple = (), counts: Optional[dict] = None,
+              front: Optional[np.ndarray] = None
               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Run the compiled NSGA-II loop; returns host (X, F, CV) arrays.
 
@@ -448,17 +461,21 @@ def jit_nsga2(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
     explicit ``X0`` (pop_size, n_var) to override the uniform init (warm
     starts — see :func:`warm_population`), and ``eval_args`` to forward
     runtime table values to ``eval_fn``.  A ``counts`` dict is filled with
-    the program's :data:`COUNTS` as ints.
+    the program's :data:`COUNTS` as ints, and a ``front`` bool array of
+    shape (pop_size,) with the final population's first-front mask.
     """
     if X0 is None:
         X0 = init_population(np.random.default_rng(seed), pop_size, n_var,
                              lower, upper, candidates)
     if runner is None:
         runner = make_jit_runner(eval_fn, n_var, lower, upper, pop_size)
-    X, F, CV, c = runner(jax.random.PRNGKey(seed),
-                         jnp.asarray(X0, dtype=jnp.int32), n_gen, *eval_args)
+    X, F, CV, first, c = runner(jax.random.PRNGKey(seed),
+                                jnp.asarray(X0, dtype=jnp.int32), n_gen,
+                                *eval_args)
     if counts is not None:
         counts.update({k: int(v) for k, v in c.items()})
+    if front is not None:
+        front[:] = np.asarray(first)
     return (np.asarray(X, dtype=np.int64), np.asarray(F, dtype=np.float64),
             np.asarray(CV, dtype=np.float64))
 
@@ -468,7 +485,8 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
                        seed: int = 0,
                        candidates: Optional[Sequence[Sequence[int]]] = None,
                        runner=None, X0s: Optional[np.ndarray] = None,
-                       eval_args: Tuple = (), counts: Optional[dict] = None
+                       eval_args: Tuple = (), counts: Optional[dict] = None,
+                       front: Optional[np.ndarray] = None
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Multi-restart search: ``n_restarts`` independently seeded runs as one
     vmapped XLA program, compiled once.
@@ -481,7 +499,9 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
     overrides the per-restart init (shape (n_restarts, pop_size, n_var));
     ``eval_args`` are broadcast to every restart (the runner must have been
     built with a matching ``n_eval_args``).  A ``counts`` dict is filled
-    with the program's :data:`COUNTS`, each a list of one int per restart.
+    with the program's :data:`COUNTS`, each a list of one int per restart,
+    and a ``front`` bool array of shape (n_restarts * pop_size,) with each
+    restart's first-front mask, flattened like X.
     """
     if X0s is None:
         X0s = np.stack([
@@ -494,11 +514,13 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
         runner = make_jit_restart_runner(eval_fn, n_var, lower, upper,
                                          pop_size,
                                          n_eval_args=len(eval_args))
-    X, F, CV, c = runner(keys, jnp.asarray(X0s, dtype=jnp.int32), n_gen,
-                         *eval_args)
+    X, F, CV, first, c = runner(keys, jnp.asarray(X0s, dtype=jnp.int32),
+                                n_gen, *eval_args)
     if counts is not None:
         counts.update({k: np.asarray(v).tolist() for k, v in c.items()})
     flat = n_restarts * pop_size
+    if front is not None:
+        front[:] = np.asarray(first).reshape(flat)
     return (np.asarray(X, dtype=np.int64).reshape(flat, n_var),
             np.asarray(F, dtype=np.float64).reshape(flat, -1),
             np.asarray(CV, dtype=np.float64).reshape(flat))
@@ -510,7 +532,8 @@ def pareto_indices_blocked(X: np.ndarray, F: np.ndarray, CV: np.ndarray,
     """Memory-bounded twin of :func:`repro.core.nsga2.pareto_indices`: the
     first-front mask comes from the tiled dominator-count primitive
     (O(n · block) peak) instead of the dense host-side sort, then the same
-    feasible-subset / unique-decision-vector selection applies."""
+    :func:`~repro.core.nsga2.front_selection` applies."""
+    from repro.core.nsga2 import front_selection
     from repro.kernels import ops
     counts = np.asarray(ops.domination_counts(
         jnp.asarray(F, jnp.float32), jnp.asarray(CV, jnp.float32),
@@ -518,7 +541,4 @@ def pareto_indices_blocked(X: np.ndarray, F: np.ndarray, CV: np.ndarray,
     first = np.flatnonzero(counts == 0)
     if not len(first):                    # numerical safety, as in the dense
         first = np.arange(len(F))
-    feas = first[CV[first] <= 0]
-    pareto = feas if len(feas) else first
-    _, uniq = np.unique(X[pareto], axis=0, return_index=True)
-    return pareto[np.sort(uniq)]
+    return front_selection(X, CV, first)

@@ -37,7 +37,8 @@ from typing import Dict, List, Optional, Protocol, Tuple, Type, runtime_checkabl
 import numpy as np
 
 from repro.core.nsga2 import (NSGA2Result, dominates_matrix,
-                              non_dominated_mask, nsga2, pareto_indices)
+                              front_selection, non_dominated_mask, nsga2,
+                              pareto_indices)
 from repro.core.partition import (Constraints, PartitionEval,
                                   PartitionEvaluator)
 from repro.explore.filters import feasible_cut_rows
@@ -363,7 +364,7 @@ class JitNSGA2Search:
 
     name = "jit_nsga2"
 
-    # above this population the final front mask comes from the tiled
+    # above this many rows the restarts' merged front comes from the tiled
     # dominator-count primitive instead of the dense host-side sort
     _DENSE_PARETO_MAX = 8192
 
@@ -451,29 +452,37 @@ class JitNSGA2Search:
             X0 = (np.stack([_start(i) for i in range(n_restarts)])
                   if n_restarts > 1 else _start(0))
         counts: Dict = {}
+        front = np.zeros(n_restarts * pop, dtype=bool)
         with phase("search/device", ctx.obs):
             if n_restarts > 1:
                 X, F, CV = jit_nsga2_restarts(
                     None, n_var=n_cuts, lower=0, upper=hi, pop_size=pop,
                     n_gen=n_gen, n_restarts=n_restarts, seed=settings.seed,
                     runner=runner, X0s=X0, eval_args=eval_args,
-                    counts=counts)
+                    counts=counts, front=front)
             else:
                 X, F, CV = jit_nsga2(
                     None, n_var=n_cuts, lower=0, upper=hi, pop_size=pop,
                     n_gen=n_gen, seed=settings.seed, runner=runner, X0=X0,
-                    eval_args=eval_args, counts=counts)
+                    eval_args=eval_args, counts=counts, front=front)
         default_registry().histogram("search_wall_s").observe(
             time.perf_counter() - t_search)
         # per restart -> summed over restarts
         counts = {k: int(np.sum(counts[k])) for k in COUNTS}
         with phase("search/front", ctx.obs):
-            if len(X) > self._DENSE_PARETO_MAX:
-                p_idx = pareto_indices_blocked(
-                    X, F, CV, block=settings.rank_block or 2048,
-                    impl=settings.rank_impl)
+            rows = np.flatnonzero(front)
+            if n_restarts == 1:
+                p_idx = front_selection(X, CV, rows)
             else:
-                p_idx = pareto_indices(X, F, CV)
+                # a row off its own restart's first front is dominated
+                # inside that restart, so the merged front lies among these
+                sub = (X[rows], F[rows], CV[rows])
+                if len(rows) > self._DENSE_PARETO_MAX:
+                    p_idx = rows[pareto_indices_blocked(
+                        *sub, block=settings.rank_block or 2048,
+                        impl=settings.rank_impl)]
+                else:
+                    p_idx = rows[pareto_indices(*sub)]
         res = NSGA2Result(X=X, F=F, CV=CV, pareto_idx=p_idx, history=[])
         evals: List[PartitionEval] = []
         with phase("search/rescore", ctx.obs):

@@ -146,7 +146,7 @@ def test_jit_runner_donates_x0():
                                     upper=9, pop_size=pop)
     key = jax.random.PRNGKey(0)
     X0 = jnp.zeros((pop, n_var), jnp.int32)
-    X, F, CV, counts = run(key, X0, 2)
+    X, F, CV, _, counts = run(key, X0, 2)
     assert X0.is_deleted(), "X0 was not donated"
     assert not key.is_deleted(), "only argnum 1 should be donated"
     assert X.shape == (pop, n_var) and F.shape[0] == pop
@@ -165,7 +165,7 @@ def test_jit_restart_runner_donates_x0s():
                                             upper=9, pop_size=pop)
     keys = jax.random.split(jax.random.PRNGKey(0), restarts)
     X0s = jnp.zeros((restarts, pop, n_var), jnp.int32)
-    X, F, CV, counts = run(keys, X0s, 2)
+    X, F, CV, _, counts = run(keys, X0s, 2)
     assert X0s.is_deleted(), "X0s was not donated"
     assert X.shape == (restarts, pop, n_var)
     assert counts["generations"].shape == (restarts,)

@@ -133,6 +133,57 @@ def test_pareto_indices_blocked_matches_dense():
     assert (got == want).all()
 
 
+# -- the runner's first-front mask --------------------------------------------
+
+def _mask_eval(X, t):
+    """Integer-valued objectives (ties on F for distinct X) and a violation
+    of ``v - t`` over v in 0..9: t 10 leaves every row feasible, t 5.5 about
+    40% infeasible, t -1 none feasible (with ties in the violation)."""
+    Xf = X.astype(jnp.float32)
+    f1 = Xf[:, 0] + Xf[:, 1]
+    f2 = (Xf[:, 1] - 9.0) ** 2 + Xf[:, 0]
+    v = ((X[:, 0] * 7 + X[:, 1] * 3) % 10).astype(jnp.float32)
+    return jnp.stack([f1, f2], axis=1), jnp.maximum(v - t, 0.0)
+
+
+@pytest.fixture(scope="module")
+def mask_runners():
+    """One compiled runner per ranking path, shared by every case."""
+    return {rb: nsga2_jax.make_jit_runner(
+        _mask_eval, n_var=2, lower=0, upper=15, pop_size=48, rank_block=rb,
+        rank_impl="ref") for rb in (0, 32)}
+
+
+@pytest.mark.parametrize("dup", [False, True], ids=["distinct", "dup_rows"])
+@pytest.mark.parametrize("feasible", [10.0, 5.5, -1.0],
+                         ids=["all_feasible", "some_feasible", "none_feasible"])
+@pytest.mark.parametrize("n_gen", [0, 3])
+@pytest.mark.parametrize("rank_block", [0, 32], ids=["dense", "tiled"])
+def test_runner_front_mask_is_first_front(mask_runners, rank_block, n_gen,
+                                          feasible, dup):
+    """The mask the runner returns is the first constrained front of the
+    population it returns, so the selection built on it equals
+    ``pareto_indices`` on the returned arrays; ``front_rows`` counts it."""
+    from repro.core.nsga2 import fast_non_dominated_sort, front_selection
+    pop = 48
+    X0 = np.random.default_rng(2).integers(0, 16, size=(pop, 2))
+    if dup:
+        X0[pop // 2:] = X0[:pop // 2]
+    counts, mask = {}, np.zeros(pop, dtype=bool)
+    X, F, CV = nsga2_jax.jit_nsga2(
+        None, n_var=2, lower=0, upper=15, pop_size=pop, n_gen=n_gen, seed=4,
+        runner=mask_runners[rank_block], X0=X0,
+        eval_args=(jnp.float32(feasible),), counts=counts, front=mask)
+    feas = CV <= 0
+    assert {10.0: feas.all(), -1.0: not feas.any()}.get(feasible, True)
+    if dup:
+        assert len(np.unique(X, axis=0)) < pop
+    first = np.flatnonzero(mask)
+    assert (first == fast_non_dominated_sort(F, CV)[0]).all()
+    assert (front_selection(X, CV, first) == pareto_indices(X, F, CV)).all()
+    assert counts["front_rows"] == mask.sum() > 0
+
+
 # -- env-forced dispatch (the CI kernel-interpret leg) ------------------------
 
 def test_rank_impl_env_override(monkeypatch):

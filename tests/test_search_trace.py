@@ -190,11 +190,11 @@ def test_peel_passes_match_numpy_fronts(rank_block):
                                     rank_block=rank_block, rank_impl="ref")
     X0 = np.random.default_rng(5).integers(LO, HI + 1, size=(POP, 2))
     key = jax.random.PRNGKey(11)
-    X1, _, _, c1 = run(key, jnp.asarray(X0, jnp.int32), 1)
+    X1, _, _, _, c1 = run(key, jnp.asarray(X0, jnp.int32), 1)
     jax.effects_barrier()
     parents0, offspring1 = seen[0], seen[1]
     seen.clear()
-    _, _, _, c2 = run(key, jnp.asarray(X0, jnp.int32), 2)
+    _, _, _, _, c2 = run(key, jnp.asarray(X0, jnp.int32), 2)
     jax.effects_barrier()
     assert (seen[1] == offspring1).all()
     offspring2 = seen[2]
