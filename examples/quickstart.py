@@ -69,7 +69,7 @@ print("\njit_nsga2:", run_spec(jit_spec).summary())
 #      is O(pop * rank_block) instead of the dense O(pop^2) that capped
 #      populations around 2k.  Set it explicitly to trade tile-loop
 #      overhead against working-set size.
-#    * rank_impl    — 'auto' (blocked jnp on CPU, Pallas kernels on TPU),
+#    * rank_impl    — 'auto' (blocked jnp on CPU, the Pallas kernel on TPU),
 #      'ref', or 'pallas' to pin a branch.
 #    * n_restarts   — >1 vmaps that many independently seeded searches into
 #      ONE compiled program (seeds seed..seed+n-1) and merges their fronts:

@@ -279,21 +279,12 @@ def front_selection(X: np.ndarray, CV: np.ndarray,
     return pareto[np.sort(uniq)]
 
 
-_JAX_TWINS = ("constrained_dominates", "domination_matrix",
-              "nondominated_rank", "crowding_by_rank", "tournament",
-              "repair", "make_offspring", "make_jit_runner",
-              "make_jit_restart_runner", "pareto_indices_blocked")
-_JAX_DIRECT = ("jit_nsga2", "jit_nsga2_restarts")
-
-
-def __getattr__(name: str):
-    """Lazy access to the JIT-compiled operator twins (``jit_`` prefixed),
-    e.g. ``nsga2.jit_nondominated_rank`` → ``nsga2_jax.nondominated_rank``.
-    Keeps this module importable without pulling in JAX."""
-    if name.startswith("jit_") and name[4:] in _JAX_TWINS:
-        import repro.core.nsga2_jax as _jx
-        return getattr(_jx, name[4:])
-    if name in _JAX_DIRECT:
-        import repro.core.nsga2_jax as _jx
-        return getattr(_jx, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+def front_of_rows(X: np.ndarray, F: np.ndarray, CV: np.ndarray,
+                  rows: np.ndarray) -> np.ndarray:
+    """``rows[pareto_indices(X[rows], F[rows], CV[rows])]`` for ascending
+    ``rows``, sorting only the first occurrence of each distinct decision
+    vector: copies share F and CV, so they never dominate one another, and
+    :func:`front_selection` keeps the first occurrence anyway."""
+    _, first = np.unique(X[rows], axis=0, return_index=True)
+    rows = rows[np.sort(first)]
+    return rows[pareto_indices(X[rows], F[rows], CV[rows])]

@@ -3,9 +3,9 @@
 Everything here is shape-static and traceable, so the *entire* generation
 loop — non-dominated ranking, crowding, binary tournaments, crossover,
 mutation, repair and the batched metric evaluation — runs as one compiled
-XLA program over fixed-shape population arrays (:func:`jit_nsga2`).  That is
-what lifts the search from the NumPy path's ~1k evals/s at pop 2048 (where
-the O(pop²) sort dominates) to accelerator-rate populations of 10k+.
+XLA program over fixed-shape population arrays (:func:`jit_nsga2`), which
+carries the search past the NumPy path's O(pop²) host sort to
+accelerator-rate populations of 10k+.
 
 Differences from the NumPy implementation, by construction:
 
@@ -525,20 +525,3 @@ def jit_nsga2_restarts(eval_fn: EvalFn, n_var: int, lower: int, upper: int,
             np.asarray(F, dtype=np.float64).reshape(flat, -1),
             np.asarray(CV, dtype=np.float64).reshape(flat))
 
-
-def pareto_indices_blocked(X: np.ndarray, F: np.ndarray, CV: np.ndarray,
-                           block: int = 2048,
-                           impl: str = "auto") -> np.ndarray:
-    """Memory-bounded twin of :func:`repro.core.nsga2.pareto_indices`: the
-    first-front mask comes from the tiled dominator-count primitive
-    (O(n · block) peak) instead of the dense host-side sort, then the same
-    :func:`~repro.core.nsga2.front_selection` applies."""
-    from repro.core.nsga2 import front_selection
-    from repro.kernels import ops
-    counts = np.asarray(ops.domination_counts(
-        jnp.asarray(F, jnp.float32), jnp.asarray(CV, jnp.float32),
-        block=block, impl=impl))
-    first = np.flatnonzero(counts == 0)
-    if not len(first):                    # numerical safety, as in the dense
-        first = np.arange(len(F))
-    return front_selection(X, CV, first)

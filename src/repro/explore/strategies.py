@@ -36,9 +36,8 @@ from typing import Dict, List, Optional, Protocol, Tuple, Type, runtime_checkabl
 
 import numpy as np
 
-from repro.core.nsga2 import (NSGA2Result, dominates_matrix,
-                              front_selection, non_dominated_mask, nsga2,
-                              pareto_indices)
+from repro.core.nsga2 import (NSGA2Result, dominates_matrix, front_of_rows,
+                              front_selection, non_dominated_mask, nsga2)
 from repro.core.partition import (Constraints, PartitionEval,
                                   PartitionEvaluator)
 from repro.explore.filters import feasible_cut_rows
@@ -344,7 +343,7 @@ class JitNSGA2Search:
     on-device, and selection/variation run as the fixed-shape operator twins
     of ``repro.core.nsga2_jax`` under one ``lax.fori_loop`` — so a whole
     search is a single XLA program and 10k+-individual populations run at
-    accelerator rate (~10× the NumPy strategy at pop 2048 on CPU).
+    accelerator rate.
 
     The final front is re-scored through the exact NumPy
     ``evaluate_batch``, so reported metrics carry no float32 drift.  When
@@ -363,10 +362,6 @@ class JitNSGA2Search:
     """
 
     name = "jit_nsga2"
-
-    # above this many rows the restarts' merged front comes from the tiled
-    # dominator-count primitive instead of the dense host-side sort
-    _DENSE_PARETO_MAX = 8192
 
     def search(self, ctx: SearchContext) -> StrategyOutput:
         """Compiled NSGA-II: one cached runner per table *shape*, gene
@@ -393,7 +388,6 @@ class JitNSGA2Search:
                                           jit_nsga2_restarts,
                                           make_jit_restart_runner,
                                           make_jit_runner,
-                                          pareto_indices_blocked,
                                           warm_population)
         from repro.core.partition_jax import make_runtime_eval_fn
 
@@ -476,13 +470,7 @@ class JitNSGA2Search:
             else:
                 # a row off its own restart's first front is dominated
                 # inside that restart, so the merged front lies among these
-                sub = (X[rows], F[rows], CV[rows])
-                if len(rows) > self._DENSE_PARETO_MAX:
-                    p_idx = rows[pareto_indices_blocked(
-                        *sub, block=settings.rank_block or 2048,
-                        impl=settings.rank_impl)]
-                else:
-                    p_idx = rows[pareto_indices(*sub)]
+                p_idx = front_of_rows(X, F, CV, rows)
         res = NSGA2Result(X=X, F=F, CV=CV, pareto_idx=p_idx, history=[])
         evals: List[PartitionEval] = []
         with phase("search/rescore", ctx.obs):

@@ -12,8 +12,7 @@ for the kernel either ran it or failed.
 
 from __future__ import annotations
 
-import os
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,24 +34,6 @@ def resolve_impl(impl: str) -> str:
     if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "ref"
     return impl
-
-
-def resolve_rank_impl(impl: str) -> str:
-    """Like :func:`resolve_impl`, with an env override for 'auto': the CI
-    kernel-interpret leg sets ``REPRO_RANK_IMPL=pallas`` so every 'auto'
-    caller exercises the Pallas branch (interpret=True) on CPU."""
-    if impl not in _IMPLS:
-        raise ValueError(f"unknown rank impl {impl!r}; valid choices: "
-                         f"{', '.join(_IMPLS)}")
-    if impl == "auto":
-        env = os.environ.get("REPRO_RANK_IMPL", "auto")
-        if env not in _IMPLS:
-            raise ValueError(
-                f"invalid REPRO_RANK_IMPL={env!r}; valid choices: "
-                f"{', '.join(_IMPLS)} (unset the variable for backend "
-                "auto-detection)")
-        impl = env
-    return resolve_impl(impl)
 
 
 # -- pareto_rank ----------------------------------------------------------------
@@ -94,7 +75,7 @@ def packed_domination(F, CV, *, block: int = 1024, impl: str = "auto",
     dominator row-tiles are sharded across its devices with
     ``jax.shard_map``.
     """
-    impl = resolve_rank_impl(impl)
+    impl = resolve_impl(impl)
     F = jnp.asarray(F, jnp.float32)
     CV = jnp.asarray(CV, jnp.float32)
     n = F.shape[0]
@@ -109,28 +90,6 @@ def packed_domination(F, CV, *, block: int = 1024, impl: str = "auto",
             out_specs=P(ax, None), check_vma=False)
         return fn(Fr, cvr, F, CV)[:W]
     return _packed_rows(F, CV, F, CV, block, impl)[:W]
-
-
-def domination_counts(F, CV, alive: Optional[jnp.ndarray] = None, *,
-                      block: int = 1024, impl: str = "auto") -> jnp.ndarray:
-    """(n,) int32 count of alive constrained dominators per individual,
-    accumulated tile-by-tile — O(n · block) peak memory.  ``counts == 0``
-    is the first constrained front (used to merge restart fronts without a
-    dense host-side sort)."""
-    impl = resolve_rank_impl(impl)
-    F = jnp.asarray(F, jnp.float32)
-    CV = jnp.asarray(CV, jnp.float32)
-    n = F.shape[0]
-    if alive is None:
-        alive = jnp.ones(n, dtype=bool)
-    if impl == "ref":
-        return _ref.domination_counts(F, CV, alive, block)
-    from repro.kernels.pareto_rank import domination_counts as k
-    bp, bq = _row_tile(block), _PALLAS_COL_TILE
-    Fp, cvp = _ref._pad_rows(F, CV, bp)
-    ap = jnp.pad(alive, (0, Fp.shape[0] - n))
-    Fq, cvq = _ref._pad_rows(F, CV, bq)
-    return k(Fp, cvp, ap, Fq, cvq, bp=bp, bq=bq, interpret=_interpret())[:n]
 
 
 def quant_matmul(x, w_q, w_scale, x_scale, impl: str = "pallas"):

@@ -102,30 +102,6 @@ def packed_domination(Fr: jnp.ndarray, cvr: jnp.ndarray,
     return words.reshape(-1, Fq.shape[0])[: (r + 31) // 32]
 
 
-def domination_counts(F: jnp.ndarray, CV: jnp.ndarray,
-                      alive: Optional[jnp.ndarray] = None,
-                      block: int = 1024) -> jnp.ndarray:
-    """Per-individual count of (alive) constrained dominators, accumulated
-    tile-by-tile over dominator row blocks — O(n * block) peak memory, the
-    streaming twin of ``domination_matrix(...).sum(axis=0)``."""
-    n = F.shape[0]
-    if alive is None:
-        alive = jnp.ones(n, dtype=bool)
-    rows = max(32, min(block, n + (-n) % 32) // 32 * 32)
-    Fp, cvp = _pad_rows(F, CV, rows)
-    ap = jnp.pad(alive, (0, Fp.shape[0] - n))
-    FT, cvq = F.T, CV[None, :]
-    def step(acc, args):
-        fp, cp, al = args
-        d = dominates_tile(fp, cp, FT, cvq) & al[:, None]
-        return acc + jnp.sum(d, axis=0, dtype=jnp.int32), None
-    acc, _ = jax.lax.scan(
-        step, jnp.zeros(n, dtype=jnp.int32),
-        (Fp.reshape(-1, rows, F.shape[1]), cvp.reshape(-1, rows, 1),
-         ap.reshape(-1, rows)))
-    return acc
-
-
 # -- window_attn ----------------------------------------------------------------
 
 def window_attn(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -287,26 +287,12 @@ def test_jit_strategy_restarts_front_superset(evaluator):
             "merged front point dominated by a single-seed point"
 
 
-@pytest.mark.parametrize("n_restarts,dense_max", [(1, None), (2, None),
-                                                  (2, 0)],
-                         ids=["one", "restarts_dense", "restarts_blocked"])
-def test_jit_strategy_pareto_idx_matches_host_front(evaluator, monkeypatch,
-                                                    n_restarts, dense_max):
+@pytest.mark.parametrize("n_restarts", [1, 2, 3])
+def test_jit_strategy_pareto_idx_matches_host_front(evaluator, n_restarts):
     """The front built from the runner's mask is ``pareto_indices`` of the
-    returned population, for one search and for merged restarts on both
-    sides of the dense/blocked size switch."""
+    returned population, for one search and for merged restarts."""
     from repro.core.nsga2 import pareto_indices
     from repro.explore import run_search
-    blocked = []
-    if dense_max is not None:
-        monkeypatch.setattr(JitNSGA2Search, "_DENSE_PARETO_MAX", dense_max)
-        orig = nsga2_jax.pareto_indices_blocked
-
-        def spy(*a, **kw):
-            blocked.append(len(a[0]))
-            return orig(*a, **kw)
-
-        monkeypatch.setattr(nsga2_jax, "pareto_indices_blocked", spy)
     res = run_search(evaluator, settings=SearchSettings(
         strategy="jit_nsga2", seed=5, pop_size=64, n_gen=8, rank_block=64,
         n_restarts=n_restarts))
@@ -314,8 +300,6 @@ def test_jit_strategy_pareto_idx_matches_host_front(evaluator, monkeypatch,
     want = pareto_indices(nsga.X, nsga.F, nsga.CV)
     assert len(want) and (nsga.pareto_idx == want).all()
     assert res.counts["front_rows"] >= len(want)
-    # the blocked extraction ran, over the restarts' front rows only
-    assert blocked == ([] if dense_max is None else [res.counts["front_rows"]])
 
 
 # -- strategy registry --------------------------------------------------------
@@ -355,16 +339,6 @@ def test_register_strategy_collision_and_override():
         STRATEGIES.pop("my_custom_search", None)
     with pytest.raises(ValueError, match="unknown strategy"):
         SearchSettings(strategy="my_custom_search")
-
-
-def test_lazy_jit_twins_via_nsga2_module():
-    """core.nsga2 exposes the twins under jit_* without importing JAX at
-    module import time."""
-    from repro.core import nsga2
-    assert nsga2.jit_repair is nsga2_jax.repair
-    assert nsga2.jit_nsga2 is nsga2_jax.jit_nsga2
-    with pytest.raises(AttributeError):
-        nsga2.jit_does_not_exist
 
 
 # -- campaign end-to-end ------------------------------------------------------

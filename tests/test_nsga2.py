@@ -1,10 +1,12 @@
 """NSGA-II invariants + convergence on a known discrete front."""
 
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.nsga2 import (crowding_distance, dominates,
-                              fast_non_dominated_sort, nsga2)
+                              fast_non_dominated_sort, front_of_rows, nsga2,
+                              pareto_indices)
 
 
 def test_dominates():
@@ -79,3 +81,53 @@ def test_nsga2_multi_cut_sorted():
                 seed=0)
     for x in res.X:
         assert list(x) == sorted(x)
+
+
+# -- merged restart fronts ----------------------------------------------------
+
+def _restart_rows(n_restarts, per_restart, dup, threshold, seed):
+    """A flattened restart population of 2 * per_restart rows a restart and
+    ``per_restart`` ascending rows picked from each.  Three genes over
+    0..39; integer objectives (ties on F for distinct vectors) and a
+    violation ``v - threshold`` over v in 0..9 that depend on the vector
+    alone, so copies share F and CV as in a search.  ``dup`` draws every
+    restart from one pool of per_restart // 8 vectors."""
+    rng = np.random.default_rng(seed)
+    n = 2 * n_restarts * per_restart
+    if dup:
+        pool = rng.integers(0, 40, size=(per_restart // 8, 3))
+        X = pool[rng.integers(0, len(pool), n)]
+    else:
+        X = np.stack(np.unravel_index(
+            rng.choice(40 ** 3, n, replace=False), (40, 40, 40)), axis=1)
+    F = np.stack([X[:, 0] + X[:, 1], 39 - X[:, 0] + X[:, 2],
+                  78 - X[:, 1] - X[:, 2] + X[:, 0] * X[:, 2] % 7],
+                 axis=1).astype(float)
+    CV = np.maximum(X @ np.array([7, 3, 5]) % 10 - threshold, 0.0)
+    rows = np.concatenate([
+        2 * per_restart * r
+        + np.sort(rng.choice(2 * per_restart, per_restart, replace=False))
+        for r in range(n_restarts)])
+    return X, F, CV, rows
+
+
+@pytest.mark.parametrize("per_restart", [64, 600])
+@pytest.mark.parametrize("n_restarts", [2, 3])
+@pytest.mark.parametrize("threshold", [10.0, 4.5, -1.0],
+                         ids=["all_feasible", "some_feasible", "none_feasible"])
+@pytest.mark.parametrize("dup", [False, True], ids=["distinct", "copies"])
+def test_front_of_rows_equals_front_of_all_rows(dup, threshold, n_restarts,
+                                                per_restart):
+    """Sorting only the distinct vectors among ``rows`` gives exactly the
+    indices, in order, of ``pareto_indices`` over every row."""
+    X, F, CV, rows = _restart_rows(n_restarts, per_restart, dup, threshold,
+                                   seed=n_restarts * 1000 + per_restart)
+    feas = CV[rows] <= 0
+    assert {10.0: feas.all(), -1.0: not feas.any()}.get(
+        threshold, 0 < feas.sum() < len(rows))
+    n_distinct = len(np.unique(X[rows], axis=0))
+    assert (n_distinct <= per_restart // 8) if dup else \
+        (n_distinct == len(rows))
+    want = rows[pareto_indices(X[rows], F[rows], CV[rows])]
+    got = front_of_rows(X, F, CV, rows)
+    assert got.dtype == want.dtype and np.array_equal(got, want)

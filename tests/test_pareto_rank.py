@@ -1,4 +1,4 @@
-"""Tiled Pareto-ranking primitives (``repro.kernels.pareto_rank`` /
+"""The tiled Pareto-ranking primitive (``repro.kernels.pareto_rank`` /
 ``kernels.ops``) vs the dense ``nsga2_jax.domination_matrix`` oracle, the
 blocked ``nondominated_rank`` path vs the dense peel (bit-exact, incl. caps,
 ragged sizes, all-infeasible rows, duplicated objective vectors), the
@@ -21,10 +21,10 @@ from repro.core.nsga2 import pareto_indices  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 
 IMPLS = ("ref", "pallas")
-# deliberately ragged vs every tile: the ref twins tile by the 32/64 blocks
-# passed below, the Pallas kernels by 256 rows x 256 columns whatever the
-# block (kernels.ops), so 600 and 777 give them 3x3 and 4x4 grids: the
-# packed-output index map and the counts' accumulation across row steps
+# deliberately ragged vs every tile: the ref twin tiles by the 32/64 blocks
+# passed below, the Pallas kernel by 256 rows x 256 columns whatever the
+# block (kernels.ops), so 600 and 777 give it 3x3 and 4x4 grids: the
+# packed-output index map across row and column steps
 SIZES = (33, 97, 130, 600, 777)
 
 
@@ -43,7 +43,7 @@ def dense_packed(F, CV):
         nsga2_jax.domination_matrix(F, CV)))
 
 
-# -- packed words / counts vs the dense oracle --------------------------------
+# -- packed words vs the dense oracle -----------------------------------------
 
 @pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("n", SIZES)
@@ -63,19 +63,6 @@ def test_packed_domination_all_infeasible(impl):
     want = dense_packed(F, CV)
     got = np.asarray(ops.packed_domination(F, CV, block=64, impl=impl))
     assert (got == want).all()
-
-
-@pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("n", SIZES)
-def test_domination_counts_match_dense(impl, n):
-    F, CV = population(n, seed=n + 1)
-    D = np.asarray(nsga2_jax.domination_matrix(F, CV))
-    got = np.asarray(ops.domination_counts(F, CV, block=32, impl=impl))
-    assert (got == D.sum(axis=0)).all()
-    alive = jnp.asarray(np.random.default_rng(n).random(n) < 0.5)
-    got_alive = np.asarray(
-        ops.domination_counts(F, CV, alive, block=32, impl=impl))
-    assert (got_alive == D[np.asarray(alive)].sum(axis=0)).all()
 
 
 # -- blocked rank vs the dense peel -------------------------------------------
@@ -102,9 +89,10 @@ def test_blocked_rank_duplicate_cv_groups():
     assert (got == want).all()
 
 
-def test_blocked_runner_equals_dense_runner():
+@pytest.mark.parametrize("impl", IMPLS)
+def test_blocked_runner_equals_dense_runner(impl):
     """The whole compiled generation loop is bit-identical whichever
-    ranking primitive it consumes."""
+    ranking primitive it consumes, the Pallas kernel included."""
     def eval_fn(X):
         f1 = jnp.sum(X, axis=1).astype(jnp.float32)
         f2 = jnp.sum((X - 12) ** 2, axis=1).astype(jnp.float32)
@@ -117,20 +105,9 @@ def test_blocked_runner_equals_dense_runner():
             eval_fn, 3, 0, 30, 48, rank_block=0), **args)
     blocked = nsga2_jax.jit_nsga2(
         eval_fn, runner=nsga2_jax.make_jit_runner(
-            eval_fn, 3, 0, 30, 48, rank_block=32), **args)
+            eval_fn, 3, 0, 30, 48, rank_block=32, rank_impl=impl), **args)
     for a, b in zip(dense, blocked):
         assert (a == b).all()
-
-
-def test_pareto_indices_blocked_matches_dense():
-    rng = np.random.default_rng(4)
-    X = rng.integers(0, 6, size=(200, 3))
-    F = rng.random((200, 2))
-    F[50:100] = F[:50]                       # duplicate decision ties
-    CV = np.where(rng.random(200) < 0.4, rng.random(200), 0.0)
-    want = pareto_indices(X, F, CV)
-    got = nsga2_jax.pareto_indices_blocked(X, F, CV, block=64)
-    assert (got == want).all()
 
 
 # -- the runner's first-front mask --------------------------------------------
@@ -184,34 +161,7 @@ def test_runner_front_mask_is_first_front(mask_runners, rank_block, n_gen,
     assert counts["front_rows"] == mask.sum() > 0
 
 
-# -- env-forced dispatch (the CI kernel-interpret leg) ------------------------
-
-def test_rank_impl_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_RANK_IMPL", "pallas")
-    assert ops.resolve_rank_impl("auto") == "pallas"
-    # explicit impls are never overridden
-    assert ops.resolve_rank_impl("ref") == "ref"
-    monkeypatch.delenv("REPRO_RANK_IMPL")
-    assert ops.resolve_rank_impl("ref") == "ref"
-    with pytest.raises(ValueError, match="rank impl"):
-        ops.resolve_rank_impl("mosaic")
-
-
-def test_rank_impl_invalid_env_raises(monkeypatch):
-    """A typo'd REPRO_RANK_IMPL must fail loudly at dispatch, naming the
-    variable and the valid choices — not silently fall through to some
-    branch (the CI kernel-interpret leg depends on the env actually
-    taking effect)."""
-    monkeypatch.setenv("REPRO_RANK_IMPL", "palas")
-    with pytest.raises(ValueError) as ei:
-        ops.resolve_rank_impl("auto")
-    msg = str(ei.value)
-    assert "REPRO_RANK_IMPL" in msg and "'palas'" in msg
-    for choice in ("auto", "ref", "pallas"):
-        assert choice in msg
-    # explicit non-auto impls bypass the env entirely, even a broken one
-    assert ops.resolve_rank_impl("ref") == "ref"
-
+# -- impl dispatch ------------------------------------------------------------
 
 def test_resolve_impl_rejects_unknown():
     """resolve_impl used to return unknown impl strings unchanged, sending

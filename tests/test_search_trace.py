@@ -26,7 +26,6 @@ from repro.explore import (ExplorationSpec, ModelRef, OnlineRepartitioner,
                            PlatformSpec, SearchSettings, SystemSpec,
                            degrade_link)
 from repro.explore.runner import explore_graph
-from repro.kernels import ops
 from repro.obs import NOOP_OBS, Obs, phase
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -248,9 +247,4 @@ def test_runner_hlo_names_every_phase_and_kernel():
     assert in_loop == set(progtrace.PHASES)
     assert any(n.startswith("jit(run)/init/") for n in names)
     assert any("packed_domination" in n.split("/") for n in names)
-    counts = jax.jit(lambda F, CV: ops.domination_counts(
-        F, CV, block=256, impl="pallas"))
-    names = _op_names(counts.lower(jnp.zeros((512, 2)), jnp.zeros(512))
-                      .compile().as_text())
-    assert any("domination_counts" in n.split("/") for n in names)
 

@@ -65,23 +65,15 @@ def _total_bytes(compiled) -> int:
 
 
 @pytest.mark.parametrize("rank_block", [256, 1024])
-@pytest.mark.parametrize("kernel", ["packed_domination", "domination_counts"])
-def test_pareto_kernel_compiles(topo, one_chip, quiet_cache, kernel,
-                                rank_block):
-    """Both ranking kernels at pop 32768 with the row tiles the ops wrapper
+def test_pareto_kernel_compiles(topo, one_chip, quiet_cache, rank_block):
+    """The ranking kernel at pop 32768 with the row tiles the ops wrapper
     picks for ``rank_block`` 256 and 1024."""
     bp, bq = ops._row_tile(rank_block), ops._PALLAS_COL_TILE
     F = _spec((ROWS, N_OBJ), jnp.float32, one_chip)
     cv = _spec((ROWS,), jnp.float32, one_chip)
-    if kernel == "packed_domination":
-        fn = jax.jit(lambda f, c: pareto_rank.packed_domination(
-            f, c, f, c, bp=bp, bq=bq, interpret=False))
-        compiled = fn.lower(F, cv).compile()
-    else:
-        alive = _spec((ROWS,), jnp.bool_, one_chip)
-        fn = jax.jit(lambda f, c, a: pareto_rank.domination_counts(
-            f, c, a, f, c, bp=bp, bq=bq, interpret=False))
-        compiled = fn.lower(F, cv, alive).compile()
+    fn = jax.jit(lambda f, c: pareto_rank.packed_domination(
+        f, c, f, c, bp=bp, bq=bq, interpret=False))
+    compiled = fn.lower(F, cv).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _total_bytes(compiled) < V5E_HBM_BYTES
 
